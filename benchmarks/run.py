@@ -65,6 +65,8 @@ def main(argv=None):
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (accuracy_bench, caesar_bench, grad_bench,
                             mac_bench, pareto_bench, quant_bench,
                             roofline_bench, serve_bench, tune_bench)

@@ -19,6 +19,7 @@ from repro.configs import CORDIC_EXEC, get_arch
 from repro.core import fixed_point as fxp
 from repro.core.activations import CordicPolicy, activate
 from repro.data.pipeline import DataConfig, SyntheticStream
+from repro.kernels.common import resolve_interpret
 from repro.kernels.cordic_mac.kernel import cordic_matmul_raw
 from repro.kernels.cordic_mac.ref import cordic_matmul_raw_ref
 from repro.models.model_zoo import build_model
@@ -34,7 +35,8 @@ def main():
     fmt = fxp.FXP16
     x = fxp.quantize(jnp.array(rng.uniform(-2, 2, (32, 32)), jnp.float32), fmt)
     w = fxp.quantize(jnp.array(rng.uniform(-1.9, 1.9, (32, 32)), jnp.float32), fmt)
-    got = cordic_matmul_raw(x, w, fmt=fmt, n_stages=5, block=(16, 16, 16))
+    got = cordic_matmul_raw(x, w, fmt=fmt, n_stages=5, block=(16, 16, 16),
+                            interpret=resolve_interpret(None))
     want = cordic_matmul_raw_ref(x, w, fmt=fmt, n_stages=5)
     print("   kernel == signed-digit oracle:", bool((got == want).all()))
 

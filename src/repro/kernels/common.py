@@ -7,12 +7,15 @@ Every kernel family (``cordic_act``, ``cordic_mac``, ``cordic_softmax``,
 
   * **platform policy** — :func:`platform` / :func:`on_tpu` /
     :func:`resolve_interpret`: Pallas kernels compile on TPU and run in
-    interpret mode everywhere else (the CPU fallback), overridable with
-    ``REPRO_KERNEL_INTERPRET=0|1``.
-  * **compiler params** — :func:`compiler_params` wraps the
-    CompilerParams/TPUCompilerParams rename (see :mod:`repro.compat`).
-  * **block sizing** — :func:`largest_divisor` / :func:`pick_block_2d` /
-    :func:`pick_block_matmul`, all answering through a three-level lookup:
+    interpret mode everywhere else (the CPU path), overridable with
+    ``REPRO_KERNEL_INTERPRET=0|1``; interpreting on a TPU warns.
+  * **compiler params** — :func:`compiler_params` builds the
+    ``pltpu.CompilerParams`` every family passes to ``pallas_call``.
+  * **block sizing** — :func:`aligned_block` / :func:`pick_block_2d` /
+    :func:`pick_block_rows` / :func:`pick_block_matmul` (tiles the TPU
+    can lower: whole axes or (8, 128) multiples, padded by the wrapper
+    where no such divisor exists), all answering through a three-level
+    lookup:
     the in-process per-(kernel, shape, dtype) cache (which
     :func:`autotune` overwrites with measured winners), then the
     persistent tuned table from :mod:`repro.kernels.tuning`, then the
@@ -35,12 +38,13 @@ import dataclasses
 import functools
 import os
 import time
+import warnings
 from typing import Any, Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core.caesar import pick_block_shape
 from repro.kernels import tuning
 
@@ -51,11 +55,12 @@ from repro.kernels import tuning
 
 @functools.lru_cache(maxsize=None)
 def platform() -> str:
-    """Primary accelerator platform: 'tpu', 'gpu' or 'cpu'."""
-    try:
-        return jax.devices()[0].platform
-    except RuntimeError:
-        return "cpu"
+    """Primary accelerator platform: 'tpu', 'gpu' or 'cpu'.
+
+    A backend that fails to initialise raises here: reporting "cpu"
+    instead would run every kernel in the interpreter on a broken chip.
+    """
+    return jax.devices()[0].platform
 
 
 def on_tpu() -> bool:
@@ -63,24 +68,33 @@ def on_tpu() -> bool:
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """The CPU-fallback policy shared by every family.
+    """The interpret-mode policy shared by every family.
 
-    Explicit ``interpret=`` wins; else ``REPRO_KERNEL_INTERPRET=0|1`` (force
-    compile under a TPU simulator / force interpret while debugging on
-    device); else interpret everywhere except real TPUs.
+    Explicit ``interpret=`` wins; else ``REPRO_KERNEL_INTERPRET=0|1``
+    (``0`` forces compilation, which fails loudly off-TPU; ``1`` forces
+    the interpreter while debugging); else interpret everywhere except
+    real TPUs.  Interpreting on a TPU is never silent: it warns, since
+    every timing taken that way measures the interpreter.
     """
-    if interpret is not None:
-        return interpret
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
-    return not on_tpu()
+    if interpret is None:
+        env = os.environ.get("REPRO_KERNEL_INTERPRET")
+        if env is None:
+            return not on_tpu()
+        interpret = env.lower() not in ("0", "false", "no")
+    if interpret and on_tpu():
+        warnings.warn("Pallas kernels are running in interpret mode on a "
+                      "TPU (interpret=True or REPRO_KERNEL_INTERPRET=1)",
+                      RuntimeWarning, stacklevel=2)
+    return interpret
 
 
-def compiler_params(*dimension_semantics: str):
-    """TPU compiler params across the CompilerParams rename."""
-    return compat.TPUCompilerParams(
-        dimension_semantics=tuple(dimension_semantics))
+def compiler_params(*dimension_semantics: str,
+                    vmem_limit_bytes: Optional[int] = None):
+    """TPU compiler params for a kernel's grid axes (and, for kernels
+    whose tiles outgrow the default scoped VMEM, a raised limit)."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -188,31 +202,68 @@ def divisor_candidates(n: int, cap: int, limit: int = 4) -> Tuple[int, ...]:
     return tuple(out)
 
 
+# Mosaic tiles a block's last two dims in (sublanes, lanes) = (8, 128)
+# for 32-bit types; narrower types pack more rows per sublane tile.
+LANES = 128
+
+
+def sublanes(dtype: Any) -> int:
+    """Row multiple of a legal tile for ``dtype`` (8 for 32-bit types)."""
+    return max(8, 32 // jnp.dtype(dtype).itemsize)
+
+
+def aligned_block(n: int, cap: int, align: int) -> int:
+    """Tile length along an axis of length ``n`` that the TPU can lower.
+
+    The whole axis when it fits under ``cap``; else the largest divisor
+    of ``n`` that is a multiple of ``align`` and at most ``cap``; else
+    ``cap`` rounded down to ``align`` (at least ``align``), which does not
+    divide ``n``: the caller pads the axis to a multiple of it.
+    """
+    n, cap = int(n), int(cap)
+    if n <= cap:
+        return n
+    top = max(align, cap // align * align)
+    for d in range(top, align - 1, -align):
+        if n % d == 0:
+            return d
+    return top
+
+
+def padded(n: int, block: int) -> int:
+    """``n`` rounded up to a multiple of ``block``."""
+    return -(-int(n) // block) * block
+
+
 def pick_block_2d(kernel: str, shape: Tuple[int, int], dtype: Any = jnp.int32,
                   max_rows: int = 256, max_cols: int = 512) -> Tuple[int, int]:
-    """Divisor-aware (rows, cols) tile for an elementwise/row-wise kernel.
+    """(rows, cols) tile for an elementwise/row-wise kernel.
 
-    Pallas BlockSpecs here require tiles that divide the array exactly, so
-    both sides shrink to the largest divisor under the cap.  Three-level
-    lookup: the in-process cache (where :func:`autotune` winners land),
-    then the persistent tuned table, then this heuristic.
+    Each side is the whole axis or a multiple of the (sublane, lane)
+    tile, preferring divisors (:func:`aligned_block`); where no aligned
+    divisor exists the tile does not divide the array and the wrapper
+    pads.  Three-level lookup: the in-process cache (where
+    :func:`autotune` winners land), then the persistent tuned table,
+    then this heuristic.
     """
     hit = _lookup(kernel, shape, dtype)
     if hit is not None:
         return hit  # type: ignore[return-value]
     r, c = shape
-    block = (largest_divisor(r, max_rows), largest_divisor(c, max_cols))
+    block = (aligned_block(r, max_rows, sublanes(dtype)),
+             aligned_block(c, max_cols, LANES))
     set_block(kernel, shape, dtype, block)
     return block
 
 
 def pick_block_rows(kernel: str, shape: Tuple[int, int],
                     dtype: Any = jnp.int32, max_rows: int = 128) -> int:
-    """Row-block for kernels that keep the feature axis whole (softmax)."""
+    """Row-block for kernels that keep the feature axis whole (softmax,
+    the wkv time block); aligned as in :func:`pick_block_2d`."""
     hit = _lookup(kernel, shape, dtype)
     if hit is not None:
         return hit[0]
-    br = largest_divisor(shape[0], max_rows)
+    br = aligned_block(shape[0], max_rows, sublanes(dtype))
     set_block(kernel, shape, dtype, (br, shape[1]))
     return br
 

@@ -120,7 +120,7 @@ def cordic_act_raw(x_raw: jax.Array, *, af: str, fmt: FxpFormat,
                    n_div: int = cordic.N_DIVISION_STAGES,
                    guard: int = GUARD_BITS,
                    block: tuple[int, int] = (256, 256),
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """Elementwise CORDIC AF on a 2D raw-int32 array (tiles must divide)."""
     assert fmt.frac_bits + guard <= 12, (
         "internal precision capped at Q12 for int32 headroom in the "

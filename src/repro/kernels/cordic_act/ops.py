@@ -22,10 +22,15 @@ def _fwd(x, af: str, fmt: FxpFormat, n_hyp: int, n_div: int, guard: int,
          block, interpret: bool):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1]) if x.ndim != 2 else x
+    r, c = x2.shape
     raw = fxp.quantize(x2, fmt)
+    # elementwise: zero padding up to whole tiles changes no real element
+    pr, pc = common.padded(r, block[0]) - r, common.padded(c, block[1]) - c
+    if pr or pc:
+        raw = jnp.pad(raw, ((0, pr), (0, pc)))
     out = cordic_act_raw(raw, af=af, fmt=fmt, n_hyp=n_hyp, n_div=n_div,
                          guard=guard, block=block, interpret=interpret)
-    return fxp.dequantize(out, fmt).reshape(shape).astype(x.dtype)
+    return fxp.dequantize(out[:r, :c], fmt).reshape(shape).astype(x.dtype)
 
 
 def cordic_act(x: jax.Array, af: str, *, fmt: FxpFormat = fxp.FXP16,

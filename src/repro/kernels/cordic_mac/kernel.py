@@ -17,6 +17,12 @@ same recurrence to a sum of signed-digit matmuls).
 Grid: (M/bm, N/bn, K/bk) with the K axis innermost ("arbitrary"), so each
 (i, j) output tile sees its K-slices back-to-back and accumulates in place —
 exactly one output-stationary pass of the systolic array per tile.
+
+The input streams in transposed, ``x.T`` in (bk, bm) tiles, so both
+operands of step ``kk`` are one dynamically indexed *row* of a ref (a
+sublane offset, which Mosaic lowers; a dynamic lane offset it refuses).
+The input row is turned into the (bm, 1) column the shift-add needs by
+an in-register transpose.
 """
 from __future__ import annotations
 
@@ -31,7 +37,7 @@ from repro.core.fixed_point import FxpFormat
 from repro.kernels import common
 
 
-def _mac_kernel(x_ref, w_ref, out_ref, *, n_stages: int, fmt: FxpFormat,
+def _mac_kernel(xt_ref, w_ref, out_ref, *, n_stages: int, fmt: FxpFormat,
                 bk: int):
     """One grid step: out_tile += CORDIC(x_tile @ w_tile)."""
 
@@ -39,8 +45,6 @@ def _mac_kernel(x_ref, w_ref, out_ref, *, n_stages: int, fmt: FxpFormat,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...]            # (bm, bk) int32 raw
-    w = w_ref[...]            # (bk, bn) int32 raw
     acc = out_ref[...]        # (bm, bn) int32 raw — the stationary tile
 
     # Angle constants E_i = 2^-i in fmt (hard-wired per pipeline stage).
@@ -49,8 +53,8 @@ def _mac_kernel(x_ref, w_ref, out_ref, *, n_stages: int, fmt: FxpFormat,
     def k_step(kk, acc):
         # One weight row enters the array; delta is a pure function of the
         # evolving weight residual, shared across the whole input column.
-        xc = jax.lax.dynamic_slice_in_dim(x, kk, 1, axis=1)        # (bm, 1)
-        z = jax.lax.dynamic_slice_in_dim(w, kk, 1, axis=0)         # (1, bn)
+        xc = jnp.transpose(xt_ref[pl.ds(kk, 1), :])               # (bm, 1)
+        z = w_ref[pl.ds(kk, 1), :]                                 # (1, bn)
         for i in range(n_stages):
             delta = jnp.where(z >= 0, jnp.int32(1), jnp.int32(-1))  # (1, bn)
             acc = acc + delta * jnp.right_shift(xc, i)              # (bm, bn)
@@ -64,8 +68,13 @@ def _mac_kernel(x_ref, w_ref, out_ref, *, n_stages: int, fmt: FxpFormat,
 def cordic_matmul_raw(x_raw: jax.Array, w_raw: jax.Array, *,
                       fmt: FxpFormat, n_stages: int,
                       block: tuple[int, int, int] = (128, 128, 128),
-                      interpret: bool = True) -> jax.Array:
-    """Raw int32 CORDIC matmul via pallas_call.  Shapes must tile evenly."""
+                      interpret: bool) -> jax.Array:
+    """Raw int32 CORDIC matmul via pallas_call.  Shapes must tile evenly.
+
+    On a TPU ``bm``/``bn`` must be multiples of 128 (or the whole axis)
+    and ``bk`` a multiple of 8: the (8, 128) tiling of the x.T, w and
+    output blocks.
+    """
     m, k = x_raw.shape
     k2, n = w_raw.shape
     assert k == k2, (x_raw.shape, w_raw.shape)
@@ -79,7 +88,7 @@ def cordic_matmul_raw(x_raw: jax.Array, w_raw: jax.Array, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
+            pl.BlockSpec((bk, bm), lambda i, j, s: (s, i)),
             pl.BlockSpec((bk, bn), lambda i, j, s: (s, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
@@ -87,4 +96,4 @@ def cordic_matmul_raw(x_raw: jax.Array, w_raw: jax.Array, *,
         compiler_params=common.compiler_params("parallel", "parallel",
                                                "arbitrary"),
         interpret=interpret,
-    )(x_raw, w_raw)
+    )(x_raw.T, w_raw)

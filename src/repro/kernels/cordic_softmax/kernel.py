@@ -49,7 +49,7 @@ def cordic_softmax_raw(x_raw: jax.Array, *, fmt: FxpFormat,
                        n_div: int = cordic.N_DIVISION_STAGES,
                        guard: int = GUARD_BITS,
                        block_rows: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     assert fmt.frac_bits + guard <= 12, "internal precision capped at Q12"
     r, c = x_raw.shape
     br = common.largest_divisor(r, block_rows)

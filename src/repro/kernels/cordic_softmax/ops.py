@@ -13,6 +13,20 @@ from repro.kernels import common
 from repro.kernels.cordic_softmax.kernel import cordic_softmax_raw
 from repro.kernels.cordic_softmax.ref import cordic_softmax_raw_ref
 
+# The whole feature axis of a row block sits in VMEM with about eight
+# int32 temporaries per element (input, output, exponentials, quotient,
+# double buffers): 2**18 elements a block keep it near 8 MiB, half the
+# 16 MiB of scoped VMEM a v5e core gives a kernel by default.
+_BLOCK_ELEMS = 1 << 18
+
+
+def block_rows(shape) -> int:
+    """Row block for a (rows, features) input: aligned, VMEM-bounded."""
+    r, c = shape
+    return common.pick_block_rows("cordic_softmax", (r, c), jnp.int32,
+                                  max_rows=max(8, min(128,
+                                                      _BLOCK_ELEMS // c)))
+
 
 @functools.partial(jax.jit, static_argnames=("fmt", "n_hyp", "n_div",
                                              "guard", "block_rows",
@@ -26,10 +40,15 @@ def _fwd(x, fmt: FxpFormat, n_hyp: int, n_div: int, guard: int,
     # *differences* matters; clamp keeps huge logits finite in fmt.
     x2 = x2 - jax.lax.stop_gradient(jnp.max(x2, axis=-1, keepdims=True))
     raw = fxp.quantize(x2, fmt)
+    # rows are independent: zero rows up to a whole row block
+    r = raw.shape[0]
+    pr = common.padded(r, block_rows) - r
+    if pr:
+        raw = jnp.pad(raw, ((0, pr), (0, 0)))
     out = cordic_softmax_raw(raw, fmt=fmt, n_hyp=n_hyp, n_div=n_div,
                              guard=guard, block_rows=block_rows,
                              interpret=interpret)
-    return fxp.dequantize(out, fmt).reshape(shape).astype(x.dtype)
+    return fxp.dequantize(out[:r], fmt).reshape(shape).astype(x.dtype)
 
 
 def _exact_softmax(x: jax.Array) -> jax.Array:
@@ -47,10 +66,9 @@ def cordic_softmax(x: jax.Array, *, fmt: FxpFormat = fxp.FXP16,
     # Pick the block OUTSIDE the jitted forward so autotuned cache entries
     # take effect (a lookup inside _fwd would be frozen into its trace).
     x2_shape = (x.size // x.shape[-1], x.shape[-1])
-    block_rows = common.pick_block_rows("cordic_softmax", x2_shape, jnp.int32)
     f = common.ste(
         functools.partial(_fwd, fmt=fmt, n_hyp=n_hyp, n_div=n_div,
-                          guard=guard, block_rows=block_rows,
+                          guard=guard, block_rows=block_rows(x2_shape),
                           interpret=interpret),
         _exact_softmax)
     return f(x)

@@ -76,13 +76,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, bq: int, bk: int,
         if with_lse:
             # Per-row log-sum-exp of the scaled scores: the O(S) residual
             # the fused backward recomputes score tiles against.
-            lse_ref[0] = m_scr[...] + jnp.log(denom)
+            lse_ref[0] = (m_scr[...] + jnp.log(denom))[None, :]
 
 
 def flash_attention_nhd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, block_q: int = 128,
                         block_k: int = 128, group: int = 1,
-                        interpret: bool = True,
+                        interpret: bool,
                         return_residuals: bool = False):
     """q: (Hq, Sq, d); k/v: (Hkv, Sk, d) with Hq = group * Hkv.
 
@@ -104,11 +104,14 @@ def flash_attention_nhd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out_specs = pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0))
     out_shape = jax.ShapeDtypeStruct((hq, sq, d), q.dtype)
     if return_residuals:
+        # (Hq, 1, Sq) so the block's last two dims are (1, bq): whole
+        # axis and lane-tiled, which the TPU lowers; (1, bq) blocks of an
+        # (Hq, Sq) array it would refuse
         out_specs = [out_specs,
-                     pl.BlockSpec((1, bq), lambda h, i, j: (h, i))]
+                     pl.BlockSpec((1, 1, bq), lambda h, i, j: (h, 0, i))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((hq, sq), jnp.float32)]
-    return pl.pallas_call(
+                     jax.ShapeDtypeStruct((hq, 1, sq), jnp.float32)]
+    res = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -127,3 +130,7 @@ def flash_attention_nhd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                                "arbitrary"),
         interpret=interpret,
     )(q, k, v)
+    if return_residuals:
+        out, lse = res
+        return out, lse.reshape(hq, sq)
+    return res

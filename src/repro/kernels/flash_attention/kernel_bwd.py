@@ -13,17 +13,16 @@ in hardware.
 
 Two passes, both tiled and both skipping causally-dead tiles, and both
 keeping the **head axis whole inside the block**: the grid runs over
-sequence tiles only, and every contraction is one hkv-batched
-``dot_general`` across all heads — fewer grid steps, fuller MXU shapes,
-and the GQA group-sum falls out of the contraction instead of a
-wrapper-side reduction:
+sequence tiles only, and every contraction is one Hq-batched
+``dot_general`` with a single contracting dim (what Mosaic lowers), the
+kv tiles broadcast over each GQA group:
 
   * **dQ** — grid (q_blocks, k_blocks), K innermost; the (Hq, bq, d) dQ
     tile accumulates in VMEM scratch across the K sweep
     (output-stationary).
   * **dK/dV** — grid (k_blocks, q_blocks), Q innermost; the (Hkv, bk, d)
-    dK and dV tiles accumulate across the Q sweep, summing each group of
-    q heads into its kv head inside the contraction.
+    dK and dV tiles accumulate across the Q sweep, each group of q
+    heads' per-head contribution summed into its kv head.
 
 Both consume ``delta = rowsum(dO * O)`` (the softmax-VJP correction term),
 computed once in jnp by the wrapper — O(S d) work, no kernel needed.
@@ -41,36 +40,52 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common
 from repro.kernels.flash_attention.kernel import NEG_INF
 
+# Every head's (bq, bk) score, probability and gradient tiles live at
+# once (~20 MB at 32 heads and 128x128 tiles): above the default scoped
+# VMEM, well inside the 128 MiB of a v5e core.
+_VMEM_LIMIT = 96 * 1024 * 1024
+
+
+def _per_q_head(x, group):
+    """(Hkv, b, d) kv tile -> (Hq, b, d): q head h reads kv head h // g."""
+    hkv, b, d = x.shape
+    return jnp.broadcast_to(x[:, None], (hkv, group, b, d)).reshape(
+        hkv * group, b, d)
+
+
+def _bdot(a, b, contract):
+    """Head-batched matmul contracting dim ``contract`` of each operand."""
+    return jax.lax.dot_general(a, b, (contract, ((0,), (0,))),
+                               preferred_element_type=jnp.float32)
+
 
 def _tile_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 q_start, k_start, *, bq, bk, scale, causal, group):
     """Recompute p and ds for one (all-heads, bq, bk) tile pair.
 
-    Returns (p, ds, q_r, k, do_r) with p/ds shaped (hkv, g, bq, bk) and
-    q_r/do_r (hkv, g, bq, d) — everything the two passes contract from.
+    Returns (p, ds, q, k, do) with p/ds shaped (Hq, bq, bk), q/do
+    (Hq, bq, d) and k (Hq, bk, d) — everything the two passes contract
+    from.
     """
-    hq = q_ref.shape[0]
-    hkv = hq // group
-    d = q_ref.shape[-1]
-    q_r = q_ref[...].astype(jnp.float32).reshape(hkv, group, bq, d)
-    do_r = do_ref[...].astype(jnp.float32).reshape(hkv, group, bq, d)
-    k = k_ref[...].astype(jnp.float32)                 # (hkv, bk, d)
-    v = v_ref[...].astype(jnp.float32)
-    lse = lse_ref[...].reshape(hkv, group, bq)
-    delta = delta_ref[...].reshape(hkv, group, bq)
-    s = jax.lax.dot_general(                           # (hkv, g, bq, bk)
-        q_r, k, (((3,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale
+    q = q_ref[...].astype(jnp.float32)                 # (hq, bq, d)
+    do = do_ref[...].astype(jnp.float32)
+    k = _per_q_head(k_ref[...].astype(jnp.float32), group)   # (hq, bk, d)
+    v = _per_q_head(v_ref[...].astype(jnp.float32), group)
+    s = _bdot(q, k, ((2,), (2,))) * scale              # (hq, bq, bk)
     if causal:
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        s = jnp.where((qpos >= kpos)[None, None], s, NEG_INF)
-    p = jnp.exp(s - lse[..., None])
-    dp = jax.lax.dot_general(                          # dO V^T
-        do_r, v, (((3,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[..., None]) * scale
-    return p, ds, q_r, k, do_r
+        s = jnp.where((qpos >= kpos)[None], s, NEG_INF)
+    p = jnp.exp(s - lse_ref[...][..., None])
+    dp = _bdot(do, v, ((2,), (2,)))                    # dO V^T
+    ds = p * (dp - delta_ref[...][..., None]) * scale
+    return p, ds, q, k, do
+
+
+def _group_sum(x, group):
+    """(Hq, b, d) per-q-head tile -> (Hkv, b, d) summed over each group."""
+    hq, b, d = x.shape
+    return x.reshape(hq // group, group, b, d).sum(axis=1)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -93,10 +108,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q_start, k_start, bq=bq, bk=bk, scale=scale, causal=causal,
             group=group)
-        dq = jax.lax.dot_general(                       # dS K: (hkv,g,bq,d)
-            ds, k, (((3,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[...] += dq.reshape(acc_scr.shape)
+        acc_scr[...] += _bdot(ds, k, ((2,), (1,)))      # dS K: (hq,bq,d)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -120,17 +132,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _step():
-        p, ds, q_r, _, do_r = _tile_grads(
+        p, ds, q, _, do = _tile_grads(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q_start, k_start, bq=bq, bk=bk, scale=scale, causal=causal,
             group=group)
-        # Contract over (group, bq): the GQA group-sum happens here.
-        dv_scr[...] += jax.lax.dot_general(             # P^T dO: (hkv,bk,d)
-            p, do_r, (((1, 2), (1, 2)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(             # dS^T Q: (hkv,bk,d)
-            ds, q_r, (((1, 2), (1, 2)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        # per-q-head P^T dO and dS^T Q, (hq, bk, d), group-summed
+        dv_scr[...] += _group_sum(_bdot(p, do, ((1,), (1,))), group)
+        dk_scr[...] += _group_sum(_bdot(ds, q, ((1,), (1,))), group)
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -142,7 +150,7 @@ def flash_attention_bwd_nhd(q: jax.Array, k: jax.Array, v: jax.Array,
                             do: jax.Array, lse: jax.Array, delta: jax.Array,
                             *, causal: bool = True, block_q: int = 128,
                             block_k: int = 128, group: int = 1,
-                            interpret: bool = True
+                            interpret: bool
                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fused backward on the (H, S, d) layout.
 
@@ -171,7 +179,8 @@ def flash_attention_bwd_nhd(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((hq, bq, d), lambda i, j: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((hq, sq, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hq, bq, d), jnp.float32)],
-        compiler_params=common.compiler_params("parallel", "arbitrary"),
+        compiler_params=common.compiler_params(
+            "parallel", "arbitrary", vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -191,7 +200,8 @@ def flash_attention_bwd_nhd(q: jax.Array, k: jax.Array, v: jax.Array,
                    jax.ShapeDtypeStruct((hkv, sk, d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hkv, bk, d), jnp.float32),
                         pltpu.VMEM((hkv, bk, d), jnp.float32)],
-        compiler_params=common.compiler_params("parallel", "arbitrary"),
+        compiler_params=common.compiler_params(
+            "parallel", "arbitrary", vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -82,7 +82,7 @@ def flash_attention_q8_nhd(q: jax.Array, k: jax.Array, v: jax.Array,
                            k_scale: jax.Array, v_scale: jax.Array, *,
                            causal: bool = True, block_q: int = 128,
                            block_k: int = 128, group: int = 1,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """q: (Hq, Sq, d) float; k/v: (Hkv, Sk, d) int8 with per-vector
     float32 scales (Hkv, Sk); Hq = group * Hkv.  Returns (Hq, Sq, d) in
     q's dtype.  Sq/Sk must tile by the blocks (clamped to divisors)."""

@@ -10,11 +10,11 @@ module persists measured winners to disk so tuning is paid once per
   * **format** — one JSON document: a ``version`` stamp plus an
     ``entries`` list of ``{kernel, shape, dtype, block}`` records, keyed
     exactly like the in-process cache.
-  * **versioning** — the stamp is (schema int, jax version, platform).
-    A table written by a different jax release or for a different
-    accelerator is *stale*: :func:`load` silently discards it, because a
-    block measured under another compiler/backend is at best noise and at
-    worst illegal.
+  * **versioning** — the stamp is (schema int, jax version, platform,
+    device kind).  A table written by a different jax release, for a
+    different accelerator or for another chip generation is *stale*:
+    :func:`load` silently discards it, because a block measured under
+    another compiler/backend is at best noise and at worst illegal.
   * **location** — ``REPRO_TUNE_CACHE`` if set, else the XDG cache dir
     (``$XDG_CACHE_HOME/repro/tuned_blocks.json``, defaulting to
     ``~/.cache/repro``).
@@ -51,21 +51,15 @@ Table = Dict[Key, Tuple[int, ...]]
 _ENV_VAR = "REPRO_TUNE_CACHE"
 
 
-def _platform() -> str:
-    """Primary accelerator platform (duplicated from common to avoid a
-    cycle; both resolve to jax.devices)."""
-    try:
-        return jax.devices()[0].platform
-    except RuntimeError:
-        return "cpu"
-
-
 def version_stamp() -> Dict[str, Any]:
-    """The validity domain of a tuned table."""
+    """The validity domain of a tuned table: blocks tuned on one chip
+    never load on another (``device_kind`` tells v5e from v6e)."""
+    dev = jax.devices()[0]
     return {
         "schema": SCHEMA_VERSION,
         "jax": jax.__version__,
-        "platform": _platform(),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
     }
 
 

@@ -9,8 +9,11 @@ per token with data-dependent per-channel decay,
 Grid (batch*heads, T/bt) with the time axis sequential ("arbitrary"); the
 state S lives in VMEM scratch across the whole sweep — the recurrent
 analogue of the SYCore output-stationary discipline (state stays, tokens
-stream).  Inside a block the bt steps run as an unrolled/fori loop of
-rank-1 updates on the VPU.
+stream).  Inside a block the bt steps run as a fori loop of rank-1
+updates on the VPU.  Each step reads token ``i`` as one dynamically
+indexed row of an f32 VMEM copy of the block (a sublane offset, which
+Mosaic lowers), turns the rows it needs into (dk, 1) columns by an
+in-register transpose, and writes its output row the same way.
 
 Bit-comparable (f32) to :mod:`repro.kernels.wkv.ref`.
 """
@@ -26,12 +29,39 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import common
 
 
+def load_block(refs, scratch):
+    """Copy this grid step's (1, bt, d) input blocks into f32 scratch."""
+    for ref, scr in zip(refs, scratch):
+        scr[...] = ref[0].astype(jnp.float32)
+
+
+def col(ref, i):
+    """Row ``i`` of a 2-d ref as a (d, 1) column."""
+    return jnp.transpose(ref[pl.ds(i, 1), :])
+
+
+def wkv_sweep(s, rs, ks, vs, ws, u_col, out_scr, bt: int):
+    """``bt`` recurrence steps from state ``s`` over the f32 block copies;
+    token ``i``'s output row lands in ``out_scr[i]``.  Returns the state
+    after the block."""
+
+    def step(i, s):
+        v_row = vs[pl.ds(i, 1), :]                       # (1, dv)
+        kv = col(ks, i) * v_row                          # (dk, dv)
+        out_scr[pl.ds(i, 1), :] = jnp.sum(
+            col(rs, i) * (s + u_col * kv), axis=0, keepdims=True)
+        return col(ws, i) * s + kv
+
+    return jax.lax.fori_loop(0, bt, step, s)
+
+
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, *rest, bt: int,
                 with_ckpt: bool):
     if with_ckpt:
-        c_ref, (s_scr,) = rest[0], rest[1:]
+        c_ref, rest = rest[0], rest[1:]
     else:
-        c_ref, (s_scr,) = None, rest
+        c_ref = None
+    s_scr, out_scr, *blk = rest
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -42,30 +72,24 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, *rest, bt: int,
         # backward (kernel_bwd.py) restarts its in-block recompute from.
         c_ref[0, 0] = s_scr[...]
 
-    r = r_ref[0].astype(jnp.float32)   # (bt, dk)
-    k = k_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)   # (bt, dv)
-    u = u_ref[0].astype(jnp.float32)   # (1, dk) broadcast row
+    load_block((r_ref, k_ref, v_ref, w_ref), blk)
+    u_col = jnp.transpose(u_ref[0].astype(jnp.float32))    # (dk, 1)
+    s_scr[...] = wkv_sweep(s_scr[...], *blk, u_col, out_scr, bt)
+    o_ref[0] = out_scr[...].astype(o_ref.dtype)
 
-    def step(i, carry):
-        s, out = carry
-        kv = k[i][:, None] * v[i][None, :]              # (dk, dv)
-        y = (r[i] * u[0])[None, :] @ kv + r[i][None, :] @ s
-        out = jax.lax.dynamic_update_slice_in_dim(out, y, i, axis=0)
-        s = w[i][:, None] * s + kv
-        return s, out
 
-    s0 = s_scr[...]
-    out0 = jnp.zeros((bt, v.shape[1]), jnp.float32)
-    s_fin, out = jax.lax.fori_loop(0, bt, step, (s0, out0))
-    s_scr[...] = s_fin
-    o_ref[0] = out.astype(o_ref.dtype)
+def block_scratch(bt: int, dk: int, dv: int):
+    """VMEM for the recurrence: state, output rows, and f32 copies of
+    the r/k/v/w blocks (in that order)."""
+    f32 = jnp.float32
+    return [pltpu.VMEM((dk, dv), f32), pltpu.VMEM((bt, dv), f32),
+            pltpu.VMEM((bt, dk), f32), pltpu.VMEM((bt, dk), f32),
+            pltpu.VMEM((bt, dv), f32), pltpu.VMEM((bt, dk), f32)]
 
 
 def wkv_recurrence(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
                    u: jax.Array, *, block_t: int = 64,
-                   interpret: bool = True,
+                   interpret: bool,
                    return_residuals: bool = False):
     """r/k/w: (BH, T, dk); v: (BH, T, dv); u: (BH, dk).  -> (BH, T, dv).
 
@@ -102,7 +126,7 @@ def wkv_recurrence(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=block_scratch(bt, dk, dv),
         compiler_params=common.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(r, k, v, w, u.reshape(bh, 1, dk))

@@ -21,6 +21,11 @@ instead of the O(T) a scan-based VJP stashes.  Grid (BH, T/bt) with the
 time axis sequential and **reversed through the index maps**: grid step i
 processes time block nt-1-i.  du accumulates into a per-(BH) output block
 revisited across the whole sweep.
+
+As in the forward, token ``i`` is a dynamically indexed row of an f32
+VMEM copy of the block; the recomputed states sit in a (bt, dk, dv)
+scratch indexed on its leading axis, and each step writes its gradient
+rows straight into the output blocks.
 """
 from __future__ import annotations
 
@@ -33,70 +38,60 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
+from repro.kernels.wkv.kernel import col, load_block
 
 
 def _wkv_bwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, dy_ref, c_ref,
-                    dr_ref, dk_ref, dv_ref, dw_ref, du_ref, a_scr, *,
-                    bt: int):
+                    dr_ref, dk_ref, dv_ref, dw_ref, du_ref, a_scr, st_scr,
+                    rs, ks, vs, ws, dys, *, bt: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         a_scr[...] = jnp.zeros_like(a_scr)   # A after the final token
         du_ref[...] = jnp.zeros_like(du_ref)
 
-    r = r_ref[0].astype(jnp.float32)    # (bt, dk)
-    k = k_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)    # (bt, dv)
-    dy = dy_ref[0].astype(jnp.float32)  # (bt, dv)
-    u = u_ref[0][0].astype(jnp.float32)  # (dk,) broadcast row
-    dk_dim, dv_dim = r.shape[1], v.shape[1]
+    load_block((r_ref, k_ref, v_ref, w_ref, dy_ref), (rs, ks, vs, ws, dys))
+    u_row = u_ref[0].astype(jnp.float32)     # (1, dk)
+    dk_dim = u_row.shape[1]
 
     # Recompute the in-block forward states from the block checkpoint:
-    # states[i] = S before token i of this block.
-    def fstep(i, carry):
-        s, states = carry
-        states = jax.lax.dynamic_update_slice(states, s[None], (i, 0, 0))
-        kv = k[i][:, None] * v[i][None, :]
-        return w[i][:, None] * s + kv, states
+    # st_scr[i] = S before token i of this block.
+    def fstep(i, s):
+        st_scr[i] = s
+        return col(ws, i) * s + col(ks, i) * vs[pl.ds(i, 1), :]
 
-    _, states = jax.lax.fori_loop(
-        0, bt, fstep,
-        (c_ref[0, 0], jnp.zeros((bt, dk_dim, dv_dim), jnp.float32)))
+    jax.lax.fori_loop(0, bt, fstep, c_ref[0, 0])
+
+    def row(x):                              # (d, 1) column -> (1, d) row
+        return jnp.transpose(x)
 
     def bstep(j, carry):
-        a, drb, dkb, dvb, dwb, du = carry    # a = A_{t+1} for token t below
+        a, du = carry                        # a = A_{t+1} for token t below
         i = bt - 1 - j
-        s_i = jax.lax.dynamic_slice(states, (i, 0, 0),
-                                    (1, dk_dim, dv_dim))[0]
-        r_i, k_i, w_i, v_i, dy_i = r[i], k[i], w[i], v[i], dy[i]
+        s_i = st_scr[i]
+        at = pl.ds(i, 1)
+        r_i, k_i, v_i, dy_i = rs[at, :], ks[at, :], vs[at, :], dys[at, :]
         vdy = jnp.sum(v_i * dy_i)
-        dr_i = (s_i @ dy_i[:, None])[:, 0] + u * k_i * vdy
+        dr_ref[0, at, :] = (row(jnp.sum(s_i * dy_i, axis=1, keepdims=True))
+                            + u_row * k_i * vdy)
         du = du + r_i * k_i * vdy
-        dk_i = r_i * u * vdy + (a @ v_i[:, None])[:, 0]
-        dv_i = jnp.sum(r_i * u * k_i) * dy_i + (k_i[None, :] @ a)[0]
-        dw_i = jnp.sum(a * s_i, axis=1)
-        a = w_i[:, None] * a + r_i[:, None] * dy_i[None, :]
-        upd = jax.lax.dynamic_update_slice_in_dim
-        return (a, upd(drb, dr_i[None], i, 0), upd(dkb, dk_i[None], i, 0),
-                upd(dvb, dv_i[None], i, 0), upd(dwb, dw_i[None], i, 0), du)
+        dk_ref[0, at, :] = (r_i * u_row * vdy
+                            + row(jnp.sum(a * v_i, axis=1, keepdims=True)))
+        dv_ref[0, at, :] = (jnp.sum(r_i * u_row * k_i) * dy_i
+                            + jnp.sum(col(ks, i) * a, axis=0, keepdims=True))
+        dw_ref[0, at, :] = row(jnp.sum(a * s_i, axis=1, keepdims=True))
+        a = col(ws, i) * a + col(rs, i) * dy_i
+        return a, du
 
-    zk = jnp.zeros((bt, dk_dim), jnp.float32)
-    zv = jnp.zeros((bt, dv_dim), jnp.float32)
-    a_fin, drb, dkb, dvb, dwb, du = jax.lax.fori_loop(
-        0, bt, bstep,
-        (a_scr[...], zk, zk, zv, zk, jnp.zeros((dk_dim,), jnp.float32)))
+    a_fin, du = jax.lax.fori_loop(
+        0, bt, bstep, (a_scr[...], jnp.zeros((1, dk_dim), jnp.float32)))
     a_scr[...] = a_fin
-    dr_ref[0] = drb.astype(dr_ref.dtype)
-    dk_ref[0] = dkb.astype(dk_ref.dtype)
-    dv_ref[0] = dvb.astype(dv_ref.dtype)
-    dw_ref[0] = dwb.astype(dw_ref.dtype)
     du_ref[0] += du
 
 
 def wkv_recurrence_bwd(r: jax.Array, k: jax.Array, v: jax.Array,
                        w: jax.Array, u: jax.Array, dy: jax.Array,
                        ckpt: jax.Array, *, block_t: int = 64,
-                       interpret: bool = True
+                       interpret: bool
                        ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                   jax.Array, jax.Array]:
     """Fused backward on the (BH, T, d) layout, all outputs float32.
@@ -118,12 +113,13 @@ def wkv_recurrence_bwd(r: jax.Array, k: jax.Array, v: jax.Array,
 
     tk_spec = pl.BlockSpec((1, bt, dk), rev)
     tv_spec = pl.BlockSpec((1, bt, dv), rev)
-    shapes = [jax.ShapeDtypeStruct((bh, t, dk), jnp.float32),
-              jax.ShapeDtypeStruct((bh, t, dk), jnp.float32),
-              jax.ShapeDtypeStruct((bh, t, dv), jnp.float32),
-              jax.ShapeDtypeStruct((bh, t, dk), jnp.float32),
-              jax.ShapeDtypeStruct((bh, dk), jnp.float32)]
-    return pl.pallas_call(
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct((bh, t, dk), f32),
+              jax.ShapeDtypeStruct((bh, t, dk), f32),
+              jax.ShapeDtypeStruct((bh, t, dv), f32),
+              jax.ShapeDtypeStruct((bh, t, dk), f32),
+              jax.ShapeDtypeStruct((bh, 1, dk), f32)]
+    dr, dk_, dv_, dw, du = pl.pallas_call(
         functools.partial(_wkv_bwd_kernel, bt=bt),
         grid=(bh, nt),
         in_specs=[
@@ -134,9 +130,14 @@ def wkv_recurrence_bwd(r: jax.Array, k: jax.Array, v: jax.Array,
                          lambda b, i, nt=nt: (b, nt - 1 - i, 0, 0)),
         ],
         out_specs=[tk_spec, tk_spec, tv_spec, tk_spec,
-                   pl.BlockSpec((1, dk), lambda b, i: (b, 0))],
+                   pl.BlockSpec((1, 1, dk), lambda b, i: (b, 0, 0))],
         out_shape=shapes,
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), f32),
+                        pltpu.VMEM((bt, dk, dv), f32),
+                        pltpu.VMEM((bt, dk), f32), pltpu.VMEM((bt, dk), f32),
+                        pltpu.VMEM((bt, dv), f32), pltpu.VMEM((bt, dk), f32),
+                        pltpu.VMEM((bt, dv), f32)],
         compiler_params=common.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(r, k, v, w, u.reshape(bh, 1, dk), dy, ckpt)
+    return dr, dk_, dv_, dw, du.reshape(bh, dk)

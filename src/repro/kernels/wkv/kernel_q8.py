@@ -19,57 +19,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import common
+from repro.kernels.wkv.kernel import block_scratch, load_block, wkv_sweep
 
 _TINY = 1e-30
 
 
 def _wkv_q8_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, s0s_ref,
-                   o_ref, sq_ref, ss_ref, s_scr, *, bt: int, nt: int):
+                   o_ref, sq_ref, ss_ref, s_scr, out_scr, *blk, bt: int,
+                   nt: int):
+    # per-row scales travel as (1, dk) rows, used as (dk, 1) columns
     @pl.when(pl.program_id(1) == 0)
     def _init():
         # dequant the incoming int8 state once; f32 thereafter
         s_scr[...] = (s0_ref[0].astype(jnp.float32)
-                      * s0s_ref[0][:, None])
+                      * jnp.transpose(s0s_ref[0]))
 
-    r = r_ref[0].astype(jnp.float32)   # (bt, dk)
-    k = k_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)   # (bt, dv)
-    u = u_ref[0].astype(jnp.float32)   # (1, dk) broadcast row
-
-    def step(i, carry):
-        s, out = carry
-        kv = k[i][:, None] * v[i][None, :]              # (dk, dv)
-        y = (r[i] * u[0])[None, :] @ kv + r[i][None, :] @ s
-        out = jax.lax.dynamic_update_slice_in_dim(out, y, i, axis=0)
-        s = w[i][:, None] * s + kv
-        return s, out
-
-    s0 = s_scr[...]
-    out0 = jnp.zeros((bt, v.shape[1]), jnp.float32)
-    s_fin, out = jax.lax.fori_loop(0, bt, step, (s0, out0))
-    s_scr[...] = s_fin
-    o_ref[0] = out.astype(o_ref.dtype)
+    load_block((r_ref, k_ref, v_ref, w_ref), blk)
+    u_col = jnp.transpose(u_ref[0].astype(jnp.float32))    # (dk, 1)
+    s_scr[...] = wkv_sweep(s_scr[...], *blk, u_col, out_scr, bt)
+    o_ref[0] = out_scr[...].astype(o_ref.dtype)
 
     @pl.when(pl.program_id(1) == nt - 1)
     def _finish():
         # requantize: same ops as core.quant_cache.quantize_blocked with
         # the value axis as the block (one scale per dk row)
         s = s_scr[...]
-        sc = jnp.max(jnp.abs(s), axis=1) * (1.0 / 127.0)       # (dk,)
-        q = jnp.clip(jnp.round(s / jnp.maximum(sc, _TINY)[:, None]),
-                     -127.0, 127.0)
+        sc = jnp.max(jnp.abs(s), axis=1, keepdims=True) * (1.0 / 127.0)
+        q = jnp.clip(jnp.round(s / jnp.maximum(sc, _TINY)), -127.0, 127.0)
         sq_ref[0] = q.astype(jnp.int8)
-        ss_ref[0] = sc
+        ss_ref[0] = jnp.transpose(sc)                          # (1, dk)
 
 
 def wkv_recurrence_q8(r: jax.Array, k: jax.Array, v: jax.Array,
                       w: jax.Array, u: jax.Array, s0: jax.Array,
                       s0_scale: jax.Array, *, block_t: int = 64,
-                      interpret: bool = True):
+                      interpret: bool):
     """r/k/w: (BH, T, dk); v: (BH, T, dv); u: (BH, dk); s0: (BH, dk, dv)
     int8 with per-row float32 scales (BH, dk).
 
@@ -83,7 +69,7 @@ def wkv_recurrence_q8(r: jax.Array, k: jax.Array, v: jax.Array,
     bt = common.largest_divisor(t, block_t)
     nt = t // bt
     kernel = functools.partial(_wkv_q8_kernel, bt=bt, nt=nt)
-    return pl.pallas_call(
+    out, s_fin, s_scale = pl.pallas_call(
         kernel,
         grid=(bh, nt),
         in_specs=[
@@ -93,20 +79,21 @@ def wkv_recurrence_q8(r: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, bt, dk), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, dk), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, dk, dv), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, dk), lambda b, i: (b, 0)),
+            pl.BlockSpec((1, 1, dk), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bt, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, dk, dv), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, dk), lambda b, i: (b, 0)),
+            pl.BlockSpec((1, 1, dk), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, dv), r.dtype),
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.int8),
-            jax.ShapeDtypeStruct((bh, dk), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, dk), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=block_scratch(bt, dk, dv),
         compiler_params=common.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(r, k, v, w, u.reshape(bh, 1, dk), s0,
-      s0_scale.astype(jnp.float32))
+      s0_scale.astype(jnp.float32).reshape(bh, 1, dk))
+    return out, s_fin, s_scale.reshape(bh, dk)

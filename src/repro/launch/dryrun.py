@@ -21,7 +21,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as PS  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.analysis import roofline  # noqa: E402
 from repro.analysis.costmodel import MeshSpec  # noqa: E402
 from repro.configs import ARCHS, LM_SHAPES, get_arch, shape_applicable  # noqa: E402
@@ -260,7 +259,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
     rules_ctx = (shd.use_rules(vkw["param_rules"]) if
                  vkw.get("param_rules") else None)
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             import contextlib
             with (rules_ctx or contextlib.nullcontext()):
                 fn, args = build_step(arch_name, shape_name, mesh,
@@ -268,7 +267,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
                 lowered = fn.lower(*args)
                 compiled = lowered.compile()
             mem = compiled.memory_analysis()
-            cost = compat.cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             hlo_text = compiled.as_text() if with_hlo else None
     except Exception as e:
         return {"arch": arch_name, "shape": shape_name, "mesh": mesh_tag,

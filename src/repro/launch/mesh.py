@@ -5,10 +5,18 @@ device state.  Single pod: 16x16 = 256 chips (data, model).  Multi-pod:
 2x16x16 = 512 chips (pod, data, model) — the pod axis is a second
 data-parallel dimension with thin inter-pod links, which the gradient
 reduction treats hierarchically (see parallel/collectives.py).
+
+Every axis is ``Auto``: the compiler propagates shardings and the model
+code places activations with ``with_sharding_constraint``.  (Bare
+``jax.make_mesh`` makes ``Explicit`` axes, under which gathers such as
+the embedding lookup need their output sharding spelled out.)  Enter a
+mesh with ``jax.set_mesh(mesh)`` so the code under it sees it through
+``jax.sharding.get_abstract_mesh()``.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.analysis.costmodel import MeshSpec
 
@@ -16,12 +24,15 @@ from repro.analysis.costmodel import MeshSpec
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape, axes):
-    """Arbitrary mesh (elastic re-scale / tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape, axes, devices=None):
+    """Arbitrary Auto-axis mesh (elastic re-scale / serving / tests)."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def mesh_spec(mesh) -> MeshSpec:

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import build_model
 from repro.runtime.serve_loop import (GangServeEngine, Request, ServeConfig,
                                       ServeEngine)
@@ -29,6 +30,7 @@ def main(argv=None):
     ServeConfig.add_args(ap)           # the shared engine flag set
     args = ap.parse_args(argv)
     ServeConfig.check_args(ap, args, gang=args.gang)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -16,6 +16,7 @@ import argparse
 from repro.configs import CORDIC_EXEC, get_arch
 from repro.configs.base import LM_SHAPES
 from repro.data.pipeline import stream_for_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.train_loop import TrainConfig, Trainer
@@ -40,6 +41,7 @@ def main(argv=None):
     ap.add_argument("--fault-at", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -28,9 +28,9 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig, ExecutionPolicy
 from repro.models import layers as L
 from repro.parallel.sharding import constrain, get_abstract_mesh

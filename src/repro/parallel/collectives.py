@@ -19,9 +19,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as PS
-
-from repro.compat import shard_map
 
 Array = jax.Array
 

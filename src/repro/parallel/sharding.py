@@ -163,17 +163,9 @@ def constrain(x: jax.Array, axes: Sequence[Optional[str]],
 
 
 def get_abstract_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-        return mesh_lib.thread_resources.env.physical_mesh
-    except Exception:
-        return None
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def data_sharding(mesh: Mesh, *, batch_axes: MeshAxes = ("pod", "data")

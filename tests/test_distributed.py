@@ -40,8 +40,9 @@ class TestShardingRules:
     def test_divisibility_fallback(self):
         out = run_py("""
             import jax, json
+            from repro.launch.mesh import make_mesh
             from repro.parallel.sharding import spec_for
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             specs = {
                 # vocab divisible by model=4 -> sharded
                 "embed": str(spec_for((1024, 64), ("vocab", "embed"), mesh)),
@@ -60,8 +61,9 @@ class TestShardingRules:
     def test_no_axis_reused_in_one_tensor(self):
         out = run_py("""
             import jax
+            from repro.launch.mesh import make_mesh
             from repro.parallel.sharding import spec_for
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             ps = spec_for((8, 4, 64), ("experts", "expert_mlp", "embed"), mesh)
             flat = []
             for e in ps:
@@ -77,8 +79,9 @@ class TestCollectives:
     def test_ring_allreduce_matches_sum(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.parallel.collectives import ring_allreduce
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = make_mesh((8,), ("data",))
             x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
             got = ring_allreduce(x, mesh, "data")
             want = np.tile(np.asarray(x).sum(0), (8, 1))
@@ -90,8 +93,9 @@ class TestCollectives:
     def test_hierarchical_allreduce(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.parallel.collectives import hierarchical_allreduce
-            mesh = jax.make_mesh((2, 4), ("pod", "data"))
+            mesh = make_mesh((2, 4), ("pod", "data"))
             x = jnp.arange(2 * 4 * 8, dtype=jnp.float32).reshape(2, 4, 8)
             got = hierarchical_allreduce(x, mesh)
             want = np.broadcast_to(np.asarray(x).sum((0, 1)), (2, 4, 8))
@@ -106,6 +110,7 @@ class TestMoEShardMap:
         """EP shard_map MoE == local dispatch (same routing, same weights)."""
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_arch
             from repro.models import moe as M
             from repro.models.model_zoo import build_model
@@ -120,8 +125,8 @@ class TestMoEShardMap:
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                                   jnp.float32)
             local, aux_l = M._moe_ffn_local(x, p, cfg, cfg.exec_policy)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
-            with mesh:
+            mesh = make_mesh((2, 4), ("data", "model"))
+            with jax.set_mesh(mesh):
                 shmap, aux_s = jax.jit(
                     lambda xx: M._moe_ffn_sharded(xx, p, cfg,
                                                   cfg.exec_policy, mesh))(x)
@@ -142,6 +147,7 @@ class TestReducedMeshDryrun:
         SPMD partitions (collectives present for sharded params)."""
         out = run_py(f"""
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_arch
             from repro.models.model_zoo import build_model
             from repro.models import spec as pspec
@@ -150,7 +156,7 @@ class TestReducedMeshDryrun:
 
             cfg = get_arch("{arch}").reduced()
             model = build_model(cfg)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             p_sh = shd.tree_shardings(model.params_spec(), mesh)
             params_abs = model.abstract_params()
             batch_abs = model.input_specs(4, 32, "train")
@@ -164,7 +170,7 @@ class TestReducedMeshDryrun:
                 p2, o2, _ = adamw.update(ocfg, g, opt_state, params)
                 return p2, o2, l
 
-            with mesh:
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(step, in_shardings=(p_sh, None, None)
                                   ).lower(params_abs, opt_abs, batch_abs)
                 compiled = lowered.compile()
@@ -186,6 +192,7 @@ class TestElasticResharding:
         out = run_py("""
             import tempfile
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from jax.sharding import NamedSharding, PartitionSpec as PS
             from repro.checkpoint.manager import CheckpointManager
             from repro.configs import get_arch
@@ -195,7 +202,7 @@ class TestElasticResharding:
 
             cfg = get_arch("glm4-9b").reduced()
             model = build_model(cfg)
-            mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+            mesh_a = make_mesh((2, 4), ("data", "model"))
             sh_a = shd.tree_shardings(model.params_spec(), mesh_a)
             params = jax.tree_util.tree_map(
                 lambda p, s: jax.device_put(p, s),
@@ -207,7 +214,7 @@ class TestElasticResharding:
                 # lose 4 chips: plan keeps tp=4, data 2->1
                 data, tp = plan_elastic_remesh(4, model_parallel=4)
                 assert (data, tp) == (1, 4)
-                mesh_b = jax.make_mesh((1, 4), ("data", "model"))
+                mesh_b = make_mesh((1, 4), ("data", "model"))
                 sh_b = shd.tree_shardings(model.params_spec(), mesh_b)
                 got = mgr.restore({"params": params},
                                   shardings={"params": sh_b})["params"]
@@ -227,6 +234,7 @@ class TestDataParallelEquivalence:
         the sharding layer must be semantics-preserving."""
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_arch
             from repro.models.model_zoo import build_model
             from repro.parallel import sharding as shd
@@ -237,10 +245,10 @@ class TestDataParallelEquivalence:
             batch = model.make_batch(jax.random.PRNGKey(1), 8, 32, "train")
             base, _ = jax.jit(lambda p, b: model.loss(p, b))(params, batch)
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             p_sh = shd.tree_shardings(model.params_spec(), mesh)
             params_s = jax.tree_util.tree_map(jax.device_put, params, p_sh)
-            with mesh:
+            with jax.set_mesh(mesh):
                 sharded, _ = jax.jit(
                     lambda p, b: model.loss(p, b))(params_s, batch)
             a, b = float(base), float(sharded)
@@ -252,6 +260,7 @@ class TestDataParallelEquivalence:
     def test_sharded_moe_loss_matches(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_mesh
             from repro.configs import get_arch
             from repro.models.model_zoo import build_model
             from repro.parallel import sharding as shd
@@ -262,10 +271,10 @@ class TestDataParallelEquivalence:
             params = model.init(jax.random.PRNGKey(0))
             batch = model.make_batch(jax.random.PRNGKey(1), 8, 32, "train")
             base, _ = jax.jit(lambda p, b: model.loss(p, b))(params, batch)
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             p_sh = shd.tree_shardings(model.params_spec(), mesh)
             params_s = jax.tree_util.tree_map(jax.device_put, params, p_sh)
-            with mesh:
+            with jax.set_mesh(mesh):
                 sharded, _ = jax.jit(
                     lambda p, b: model.loss(p, b))(params_s, batch)
             a, b = float(base), float(sharded)

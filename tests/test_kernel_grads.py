@@ -101,7 +101,8 @@ class TestFlashFusedBackward:
         k = jnp.array(rng.normal(size=(hq, s, d)), jnp.float32)
         v = jnp.array(rng.normal(size=(hq, s, d)), jnp.float32)
         out, lse = flash_attention_nhd(q, k, v, causal=False, block_q=32,
-                                       block_k=32, return_residuals=True)
+                                       block_k=32, return_residuals=True,
+                                       interpret=True)
         scores = jnp.einsum("hqd,hkd->hqk", q, k) / (d ** 0.5)
         want = jax.nn.logsumexp(scores, axis=-1)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
@@ -109,7 +110,8 @@ class TestFlashFusedBackward:
         # the plain call is unchanged
         np.testing.assert_allclose(
             np.asarray(flash_attention_nhd(q, k, v, causal=False,
-                                           block_q=32, block_k=32)),
+                                           block_q=32, block_k=32,
+                                           interpret=True)),
             np.asarray(out), atol=1e-6)
 
     def test_raw_bwd_kernel_vs_ref(self, rng):
@@ -121,11 +123,11 @@ class TestFlashFusedBackward:
         do = jnp.array(rng.normal(size=(hq, s, d)), jnp.float32)
         o, lse = flash_attention_nhd(q, k, v, causal=True, block_q=32,
                                      block_k=32, group=group,
-                                     return_residuals=True)
+                                     return_residuals=True, interpret=True)
         delta = jnp.einsum("hsd,hsd->hs", do, o)
         dq, dk, dv = flash_attention_bwd_nhd(
             q, k, v, do, lse, delta, causal=True, block_q=32, block_k=32,
-            group=group)
+            group=group, interpret=True)
         rdq, rdk, rdv = attention_bwd_ref(q, k, v, do, causal=True,
                                           group=group)
         np.testing.assert_allclose(np.asarray(dq), np.asarray(rdq),
@@ -190,7 +192,7 @@ class TestWkvFusedBackward:
         w = jnp.array(rng.uniform(0.1, 0.9, (bh, t, d)), jnp.float32)
         u = jnp.array(rng.normal(size=(bh, d)), jnp.float32)
         _, ckpt = wkv_recurrence(r, k, v, w, u, block_t=bt,
-                                 return_residuals=True)
+                                 return_residuals=True, interpret=True)
         assert ckpt.shape == (bh, t // bt, d, d)
         # state before token 0 is zero
         np.testing.assert_allclose(np.asarray(ckpt[:, 0]), 0.0)
@@ -211,8 +213,9 @@ class TestWkvFusedBackward:
         u = jnp.array(rng.normal(size=(bh, d)), jnp.float32)
         dy = jnp.array(rng.normal(size=(bh, t, d)), jnp.float32)
         _, ckpt = wkv_recurrence(r, k, v, w, u, block_t=bt,
-                                 return_residuals=True)
-        got = wkv_recurrence_bwd(r, k, v, w, u, dy, ckpt, block_t=bt)
+                                 return_residuals=True, interpret=True)
+        got = wkv_recurrence_bwd(r, k, v, w, u, dy, ckpt, block_t=bt,
+                                 interpret=True)
         want = wkv_bwd_ref(r, k, v, w, u, dy)
         for name, g_, w_ in zip("dr dk dv dw du".split(), got, want):
             np.testing.assert_allclose(np.asarray(g_), np.asarray(w_),

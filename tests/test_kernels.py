@@ -95,7 +95,8 @@ class TestCordicActKernel:
     @pytest.mark.parametrize("shape", [(8, 128), (64, 64), (32, 96)])
     def test_bit_exact_vs_ref(self, af, fmt, shape, rng):
         x = fxp.quantize(jnp.array(rng.uniform(-6, 6, shape), jnp.float32), fmt)
-        got = cordic_act_raw(x, af=af, fmt=fmt, block=(8, 32))
+        got = cordic_act_raw(x, af=af, fmt=fmt, block=(8, 32),
+                             interpret=True)
         want = cordic_act_raw_ref(x, af=af, fmt=fmt)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -128,7 +129,7 @@ class TestCordicSoftmaxKernel:
     def test_bit_exact_vs_ref(self, fmt, shape, rng):
         x = fxp.quantize(
             jnp.array(rng.normal(size=shape) * 2 - 3, jnp.float32), fmt)
-        got = cordic_softmax_raw(x, fmt=fmt, block_rows=8)
+        got = cordic_softmax_raw(x, fmt=fmt, block_rows=8, interpret=True)
         want = cordic_softmax_raw_ref(x, fmt=fmt)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -179,7 +180,7 @@ class TestFlashAttentionKernel:
         k = jnp.array(rng.normal(size=(hkv, sk, d)), jnp.float32)
         v = jnp.array(rng.normal(size=(hkv, sk, d)), jnp.float32)
         got = flash_attention_nhd(q, k, v, causal=causal, block_q=bq,
-                                  block_k=bk, group=g)
+                                  block_k=bk, group=g, interpret=True)
         want = attention_nhd_ref(q, k, v, causal=causal, group=g)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -205,7 +206,8 @@ class TestFlashAttentionKernel:
         q = jnp.array(rng.normal(size=(2, 64, 32)), jnp.bfloat16)
         k = jnp.array(rng.normal(size=(2, 64, 32)), jnp.bfloat16)
         v = jnp.array(rng.normal(size=(2, 64, 32)), jnp.bfloat16)
-        got = flash_attention_nhd(q, k, v, block_q=32, block_k=32)
+        got = flash_attention_nhd(q, k, v, block_q=32, block_k=32,
+                                  interpret=True)
         want = attention_nhd_ref(q, k, v)
         assert got.dtype == jnp.bfloat16
         np.testing.assert_allclose(
@@ -226,7 +228,7 @@ class TestWkvKernel:
         v = jnp.array(rng.normal(size=(bh, t, dv)), jnp.float32)
         w = jnp.array(rng.uniform(0.3, 1.0, size=(bh, t, dk)), jnp.float32)
         u = jnp.array(rng.normal(size=(bh, dk)), jnp.float32)
-        got = wkv_recurrence(r, k, v, w, u, block_t=bt)
+        got = wkv_recurrence(r, k, v, w, u, block_t=bt, interpret=True)
         want = wkv_recurrence_ref(r, k, v, w, u)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=5e-5, rtol=5e-5)

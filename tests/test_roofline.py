@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.analysis import roofline
 from repro.analysis.costmodel import MeshSpec, param_count, step_costs
 from repro.configs import ARCHS, LM_SHAPES, get_arch
@@ -21,7 +20,7 @@ def test_xla_cost_analysis_counts_scan_body_once():
 
     x = jax.ShapeDtypeStruct((64, 128), jnp.float32)
     w = jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)
-    ca = compat.cost_analysis(jax.jit(f).lower(x, w).compile())
+    ca = jax.jit(f).lower(x, w).compile().cost_analysis()
     one_layer = 2 * 64 * 128 * 128
     ratio = ca["flops"] / (8 * one_layer)
     assert 0.1 < ratio < 0.2  # ~1/8: body counted once
@@ -61,8 +60,8 @@ def test_analytic_flops_calibrated_against_hlo():
     def loss_grad(p, b):
         return jax.grad(lambda pp: model.loss(pp, b)[0])(p)
 
-    ca = compat.cost_analysis(
-        jax.jit(loss_grad).lower(params_abs, batch_abs).compile())
+    ca = jax.jit(loss_grad).lower(params_abs, batch_abs).compile(
+    ).cost_analysis()
     hlo_flops = ca["flops"]
 
     import dataclasses

@@ -134,7 +134,13 @@ def test_rollback_after_rejection(served, arch):
     stc = model.spec_commit(stv, rec, jnp.asarray([3], jnp.int32))
     np.testing.assert_array_equal(np.asarray(stc.pos).ravel(),
                                   [len(prompt) + 3])
-    # recurrent fields equal the plain-decode state after 3 steps
+    # recurrent fields equal the plain-decode state after 3 steps, to the
+    # last bit but one: verify runs the window's steps inside one program
+    # and decode one program per token, and XLA may fuse a state update's
+    # multiply-add (w*S + kv, decay*h + drive) into one FMA in one and
+    # round twice in the other.  Each committed step may move the f32
+    # state by an ulp of its largest element; the bf16 logits above and
+    # below stay bit-identical.
     st3 = st0
     for tok in window[:3]:
         _, st3 = dec(params, st3, jnp.asarray([[tok]], jnp.int32))
@@ -142,9 +148,10 @@ def test_rollback_after_rejection(served, arch):
         a, b = getattr(stc, f), getattr(st3, f)
         if a is None:
             continue
-        np.testing.assert_array_equal(
-            np.asarray(a.astype(jnp.float32)),
-            np.asarray(b.astype(jnp.float32)), err_msg=(arch, f))
+        b = np.asarray(b.astype(jnp.float32))
+        np.testing.assert_allclose(
+            np.asarray(a.astype(jnp.float32)), b, rtol=0,
+            atol=3 * np.spacing(np.abs(b).max()), err_msg=(arch, f))
     # and decode continues identically despite the stale rejected writes
     lg_c, _ = model.decode_step(params, stc,
                                 {"tokens": jnp.asarray([[seq[3]]],

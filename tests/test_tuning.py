@@ -53,6 +53,14 @@ class TestTableIO:
         table_path.write_text(json.dumps(doc))
         assert tuning.load() == {}
 
+    def test_device_kind_mismatch_invalidates(self, table_path):
+        tuning.save({KEY: (8, 16)})
+        doc = json.loads(table_path.read_text())
+        assert doc["version"]["device_kind"] == jax.devices()[0].device_kind
+        doc["version"]["device_kind"] = "TPU v5 lite"
+        table_path.write_text(json.dumps(doc))
+        assert tuning.load() == {}
+
     def test_schema_bump_invalidates(self, table_path):
         tuning.save({KEY: (8, 16)})
         doc = json.loads(table_path.read_text())
