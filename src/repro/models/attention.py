@@ -149,6 +149,7 @@ def chunked_attention(q: Array, k: Array, v: Array, cfg: ArchConfig,
     return ctx.astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attention(q, k, v, cfg: ArchConfig, pol: ExecutionPolicy, q_pos, k_pos,
               window=None) -> Array:
     window = window if window is not None else jnp.int32(2 ** 30)
@@ -265,6 +266,7 @@ def _attend_verify(q: Array, keys: Array, vals: Array, posv: Array,
     return ctx.reshape(b, kq, hq, dh)
 
 
+@jax.named_scope("attention")
 def decode_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
                      cache_v: Array, pos: Array, cfg: ArchConfig,
                      pol: ExecutionPolicy, window,
@@ -327,6 +329,7 @@ def decode_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
     return ctx, cache_k, cache_v
 
 
+@jax.named_scope("attention")
 def verify_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
                      cache_v: Array, pos: Array, cfg: ArchConfig,
                      pol: ExecutionPolicy, window,
@@ -453,6 +456,7 @@ def _paged_write(pool: Array, idx: Array, new: Array) -> Array:
     return flat.reshape(pool.shape)
 
 
+@jax.named_scope("attention")
 def paged_decode_attention(q: Array, k_new: Array, v_new: Array,
                            pool_k: Array, pool_v: Array, table: Array,
                            pos: Array, cfg: ArchConfig,
@@ -501,6 +505,7 @@ def paged_decode_attention(q: Array, k_new: Array, v_new: Array,
     return ctx, pool_k, pool_v
 
 
+@jax.named_scope("attention")
 def paged_verify_attention(q: Array, k_new: Array, v_new: Array,
                            pool_k: Array, pool_v: Array, table: Array,
                            pos: Array, cfg: ArchConfig,

@@ -73,6 +73,7 @@ def apply_rope(x: Array, angles: Array) -> Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
 
+@jax.named_scope("mlp")
 def swiglu(x: Array, w_gate: Array, w_up: Array, w_down: Array,
            policy: ExecutionPolicy, act: str = "silu") -> Array:
     g = dense(x, w_gate, policy)

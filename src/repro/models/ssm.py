@@ -58,6 +58,7 @@ def _last_valid(x: Array, lengths) -> Array:
         idx, (x.shape[0], 1, x.shape[2])), axis=1)[:, 0, :]
 
 
+@jax.named_scope("time_mix")
 def rwkv6_timemix(x: Array, p: Rwkv6Params, cfg: ArchConfig,
                   pol: ExecutionPolicy, state: Tuple[Array, Array],
                   mask: Array = None, lengths: Array = None,
@@ -76,6 +77,9 @@ def rwkv6_timemix(x: Array, p: Rwkv6Params, cfg: ArchConfig,
     step*, (B, T, H, dk, dv) float32 — the per-position checkpoints a
     speculative ``verify_step`` rolls back to when drafts are rejected.
     Only sensible for short T (the verify window).
+
+    The recurrence runs under the named scope ``wkv``, the rest of the
+    mixer under ``time_mix``, so a profile tells the two apart.
     """
     b, t, d = x.shape
     h = cfg.n_heads
@@ -104,11 +108,14 @@ def rwkv6_timemix(x: Array, p: Rwkv6Params, cfg: ArchConfig,
         # decode/verify fast path: one recurrence step, no chunk
         # scaffolding (same primitive ops and casts as the scanned step
         # below — bit-identical, just without the length-1 scans)
-        r1, k1, v1, w1 = (a[:, 0].astype(jnp.float32) for a in (r, k, v, w))
-        S = s0.astype(jnp.float32)
-        kv = k1[..., :, None] * v1[..., None, :]               # (B,H,dk,dv)
-        out = jnp.einsum("bhk,bhkv->bhv", r1, S + u[..., None] * kv)[:, None]
-        S = w1[..., None] * S + kv
+        with jax.named_scope("wkv"):
+            r1, k1, v1, w1 = (a[:, 0].astype(jnp.float32)
+                              for a in (r, k, v, w))
+            S = s0.astype(jnp.float32)
+            kv = k1[..., :, None] * v1[..., None, :]           # (B,H,dk,dv)
+            out = jnp.einsum("bhk,bhkv->bhv", r1,
+                             S + u[..., None] * kv)[:, None]
+            S = w1[..., None] * S + kv
         res = _timemix_out(out, x, g, p, pol, lengths, S)
         return res + (S[:, None],) if return_states else res
 
@@ -133,9 +140,10 @@ def rwkv6_timemix(x: Array, p: Rwkv6Params, cfg: ArchConfig,
     def to_chunks(a):  # (B,T,H,dk) -> (n_chunks, chunk, B, H, dk)
         return a.transpose(1, 0, 2, 3).reshape(n_chunks, chunk, b, h, dk)
 
-    S, ys = jax.lax.scan(scan_chunk, s0.astype(jnp.float32),
-                         (to_chunks(r), to_chunks(k), to_chunks(v),
-                          to_chunks(w)))
+    with jax.named_scope("wkv"):
+        S, ys = jax.lax.scan(scan_chunk, s0.astype(jnp.float32),
+                             (to_chunks(r), to_chunks(k), to_chunks(v),
+                              to_chunks(w)))
     s_steps, out = ys if return_states else (None, ys)
     out = out.reshape(t, b, h, dk).transpose(1, 0, 2, 3)        # (B,T,H,dk)
     res = _timemix_out(out, x, g, p, pol, lengths, S)
@@ -168,6 +176,7 @@ class Rwkv6ChannelParams(NamedTuple):
     wr: Array     # (D, D)
 
 
+@jax.named_scope("channel_mix")
 def rwkv6_channelmix(x: Array, p: Rwkv6ChannelParams, cfg: ArchConfig,
                      pol: ExecutionPolicy, x_prev: Array,
                      lengths: Array = None) -> Tuple[Array, Array]:

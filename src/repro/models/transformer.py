@@ -166,6 +166,13 @@ def layer_windows(cfg: ArchConfig, seq_len: int) -> np.ndarray:
 # Block forward (training / prefill)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("head")
+def _lm_head(x: Array, params: Dict[str, Any], pol: ExecutionPolicy
+             ) -> Array:
+    """The vocabulary projection, under the named scope ``head``."""
+    return L.dense(x, params["lm_head"], pol)
+
+
 def _attn_params(bp: Dict[str, Array], cfg: ArchConfig) -> A.AttnParams:
     return A.AttnParams(bp["attn"]["wq"], bp["attn"]["wk"], bp["attn"]["wv"],
                         bp["attn"]["wo"], bp["attn"].get("bq"),
@@ -256,7 +263,7 @@ def forward(params: Dict[str, Any], batch: Dict[str, Array],
     (x, aux), _ = jax.lax.scan(block_fn, (x, jnp.float32(0.0)),
                                (params["blocks"], windows))
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = L.dense(x, params["lm_head"], pol)
+    logits = _lm_head(x, params, pol)
     if cfg.n_codebooks:
         logits = logits.reshape(b, s, cfg.n_codebooks, cfg.vocab_size)
     return logits
@@ -284,7 +291,7 @@ def loss_fn(params, batch, cfg: ArchConfig,
     (x, aux), _ = jax.lax.scan(block_fn, (x, jnp.float32(0.0)),
                                (params["blocks"], windows))
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = L.dense(x, params["lm_head"], pol)
+    logits = _lm_head(x, params, pol)
     if cfg.n_codebooks:
         logits = logits.reshape(b, s, cfg.n_codebooks, cfg.vocab_size)
     ce = L.cross_entropy(logits, batch["labels"], batch.get("mask"))
@@ -530,7 +537,7 @@ def decode_step(params: Dict[str, Any], state: DecodeState,
             new_state = state._replace(cache_k=ck, cache_v=cv, pos=pos + 1)
 
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = L.dense(x, params["lm_head"], pol)
+    logits = _lm_head(x, params, pol)
     if cfg.n_codebooks:
         logits = logits.reshape(b, 1, cfg.n_codebooks, cfg.vocab_size)
     return logits, new_state
@@ -685,7 +692,7 @@ def prefill(params, batch, cfg: ArchConfig,
         x_last = jnp.take_along_axis(
             x, jnp.broadcast_to(idx, (b, 1, x.shape[-1])), axis=1)
     x_last = L.rms_norm(x_last, params["ln_f"], cfg.norm_eps)
-    logits = L.dense(x_last, params["lm_head"], pol)
+    logits = _lm_head(x_last, params, pol)
     return logits, state
 
 
@@ -1024,7 +1031,7 @@ def verify_step(params: Dict[str, Any], state: DecodeState,
         rec_stack.update(zip(_RING_KEYS, ring_ev))
 
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = L.dense(x, params["lm_head"], pol)
+    logits = _lm_head(x, params, pol)
     if cfg.n_codebooks:
         logits = logits.reshape(b, kq, cfg.n_codebooks, cfg.vocab_size)
     return logits, new_state, rec_stack

@@ -61,6 +61,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import CacheSpec
@@ -334,11 +335,17 @@ class ServeMetrics:
     the exact dict shape the bench JSON writers have always serialized.
     """
 
-    # token/step counters (accumulate over the engine lifetime)
+    # token/step counters (accumulate over the engine lifetime);
+    # prefill_positions counts the row-positions the prefill and extend
+    # programs computed (max_batch x bucket a call), so 1 - prefill_tokens
+    # / prefill_positions is the share of that work spent on padding
     prefill_tokens: int = 0
+    prefill_positions: int = 0
     decode_tokens: int = 0
     decode_steps: int = 0
-    # per-serve() averages/rates (recomputed at the end of each call)
+    # per-serve() averages/rates (recomputed at the end of each call);
+    # queue_wait_s is the mean of admit_started_at - submitted_at over the
+    # call's requests whose admission began
     queue_wait_s: float = 0.0
     slot_occupancy: float = 0.0
     wall_s: float = 0.0
@@ -420,9 +427,22 @@ class Request:
     # terminal disposition: "done" (full budget), "shed" (backpressure
     # victim, empty output), "timeout" (deadline expired)
     status: str = "pending"
+    # host stamps (time.monotonic()); serve() clears the admission and
+    # token stamps when it takes the request
     submitted_at: float = 0.0     # absolute arrival time
-    admitted_at: float = 0.0      # absolute prefill time
+    # when the admission that placed the request began: serve() popped it
+    # from the waiting queue (stamped again if a paged admission sends it
+    # back to the queue and it is popped again)
+    admit_started_at: Optional[float] = None
+    admitted_at: float = 0.0      # the host holds the first token
     done_at: float = 0.0
+    # when the host held each output token: token 0 after the admission's
+    # pull, token k after the pull of the decode step that emitted it (a
+    # speculative step stamps each token it accepted).  A request resumed
+    # from a snapshot stamps only the tokens emitted after it resumed.
+    token_times: List[float] = dataclasses.field(default_factory=list)
+    # prompt tokens the radix prefix cache supplied (paged admissions)
+    prefix_hit_tokens: int = 0
 
 
 @dataclasses.dataclass
@@ -690,8 +710,6 @@ class ServeEngine:
         self.metrics = ServeMetrics()
         self._occ_num = 0
         self._occ_den = 0
-        self._wait_sum = 0.0
-        self._n_done = 0
         # -- fault tolerance -----------------------------------------------
         # snapshotted requests awaiting re-admission (rid -> _Parked)
         self._parked: Dict[int, _Parked] = {}
@@ -755,10 +773,12 @@ class ServeEngine:
         (device argmax); only steps where some live request actually
         samples pull the full (B, vocab) float rows."""
         b = self.max_batch
-        if self.greedy or not sampling:
-            return np.asarray(jnp.argmax(logits.reshape(b, -1),
-                                         axis=-1)), None
-        return None, np.asarray(logits.astype(jnp.float32)).reshape(b, -1)
+        with TraceAnnotation("serve.pull"):
+            if self.greedy or not sampling:
+                return np.asarray(jnp.argmax(logits.reshape(b, -1),
+                                             axis=-1)), None
+            return None, np.asarray(
+                logits.astype(jnp.float32)).reshape(b, -1)
 
     def _next_token(self, slot: _Slot, i: int, ids, rows) -> int:
         return (int(ids[i]) if rows is None
@@ -798,7 +818,6 @@ class ServeEngine:
         if r.status == "pending":       # deadline retire pre-sets "timeout"
             r.status = "done"
         done.append(r)
-        self._n_done += 1
         self.events.append(("retire", r.rid, -1 if i is None else i,
                             int(self.metrics["decode_steps"])))
         if i is not None:
@@ -905,147 +924,157 @@ class ServeEngine:
         stray K/V writes sit past ``pos`` (or drop at table sentinels),
         invisible until overwritten — the spec-decode rollback invariant.
         """
-        b = self.max_batch
-        page = self.page_size
-        now = time.monotonic()
-        plan = []                     # (req, slot, matched, nodes)
-        leftover: List[Request] = []
-        free_iter = iter(free)
-        for r in group:
-            m, nodes = (self.radix.match(r.prompt)
-                        if self.radix is not None else (0, []))
-            if self.allocator is not None:
-                taken: List[int] = []
-                for node in nodes:    # slot's own refs on shared pages
-                    self.allocator.ref(node.block)
-                    taken.append(node.block)
-                new_blocks: List[int] = []
-                dry = False
-                for _ in range(m // page, (len(r.prompt) - 1) // page + 1):
-                    blk = self._alloc_block()
-                    if blk is None:
-                        dry = True
-                        break
-                    new_blocks.append(blk)
-                if dry:               # roll this request back, keep going
-                    for blk in taken + new_blocks:
-                        self.allocator.free(blk)
-                    leftover.append(r)
-                    continue
-                slot_i = next(free_iter)
-                row = self._tables[slot_i]
-                for p, node in enumerate(nodes):
-                    row[p] = node.block
-                for q, blk in enumerate(new_blocks):
-                    row[m // page + q] = blk
-            else:
-                slot_i = next(free_iter)
-            if self.radix is not None:
-                self.radix.hits += m // page
-                self.radix.misses += (len(r.prompt) - 1) // page + 1 \
-                    - m // page
-            plan.append((r, slot_i, m, nodes))
-        if not plan:
+        with TraceAnnotation("serve.admit",
+                             step=int(self.metrics["decode_steps"])) as span:
+            b = self.max_batch
+            page = self.page_size
+            plan = []                     # (req, slot, matched, nodes)
+            leftover: List[Request] = []
+            free_iter = iter(free)
+            for r in group:
+                m, nodes = (self.radix.match(r.prompt)
+                            if self.radix is not None else (0, []))
+                if self.allocator is not None:
+                    taken: List[int] = []
+                    for node in nodes:    # slot's own refs on shared pages
+                        self.allocator.ref(node.block)
+                        taken.append(node.block)
+                    new_blocks: List[int] = []
+                    dry = False
+                    for _ in range(m // page, (len(r.prompt) - 1) // page + 1):
+                        blk = self._alloc_block()
+                        if blk is None:
+                            dry = True
+                            break
+                        new_blocks.append(blk)
+                    if dry:               # roll this request back, keep going
+                        for blk in taken + new_blocks:
+                            self.allocator.free(blk)
+                        leftover.append(r)
+                        continue
+                    slot_i = next(free_iter)
+                    row = self._tables[slot_i]
+                    for p, node in enumerate(nodes):
+                        row[p] = node.block
+                    for q, blk in enumerate(new_blocks):
+                        row[m // page + q] = blk
+                else:
+                    slot_i = next(free_iter)
+                if self.radix is not None:
+                    self.radix.hits += m // page
+                    self.radix.misses += (len(r.prompt) - 1) // page + 1 \
+                        - m // page
+                plan.append((r, slot_i, m, nodes))
+            if not plan:
+                return leftover
+            # the extend program's suffix bucket
+            bucket = self._bucket(max(len(r.prompt) - m
+                                      for r, _, m, _ in plan))
+            span.set_metadata(rows=len(plan), bucket=bucket)
+            self.metrics["prefill_positions"] += b * bucket
+
+            # one reset program: pos + recurrent snapshots for warm slots
+            # (rec keys are the state's recurrent fields — fixed per family,
+            # so the reset trace is reused across admissions)
+            slots_arr = np.full((b,), b, np.int32)     # sentinel rows drop
+            pos_vals = np.zeros((b,), np.int32)
+            rec: Dict[str, np.ndarray] = {}
+            for name in ("x_prev", "cm_prev", "wkv", "conv_tail", "ssm_h"):
+                leaf = getattr(self._state, name, None)
+                if leaf is not None:
+                    rec[name] = np.zeros((leaf.shape[0], b) + tuple(
+                        leaf.shape[2:]), np.float32)
+            for j, (r, slot_i, m, nodes) in enumerate(plan):
+                slots_arr[j] = slot_i
+                pos_vals[j] = m
+                if m and rec:
+                    snap = nodes[-1].rec
+                    for name, arr in rec.items():
+                        arr[:, j] = snap[name]
+            self._state = self._reset(self._st(), slots_arr, pos_vals, rec)
+
+            # one extend program at the suffix bucket
+            toks = np.zeros((b, bucket), np.int32)
+            adv = np.zeros((b,), np.int32)
+            for r, slot_i, m, _ in plan:
+                sfx = len(r.prompt) - m
+                toks[slot_i, :sfx] = r.prompt[m:]
+                adv[slot_i] = sfx
+            ids_dev, logits, self._state, rec_stack = self._extend(
+                self.params, self._st(), toks, adv)
+            with TraceAnnotation("serve.pull"):
+                ids = np.asarray(ids_dev)                     # (B, bucket)
+                now = time.monotonic()
+                rows = None
+                if not self.greedy and any(r.temperature > 0.0
+                                           for r, _, _, _ in plan):
+                    rows = np.asarray(
+                        logits.astype(jnp.float32))           # (B, bkt, V)
+                rec_np = ({name: np.asarray(stk, np.float32)
+                           for name, stk in rec_stack.items()}
+                          if self.radix is not None else {})
+
+            for r, slot_i, m, nodes in plan:
+                sfx = len(r.prompt) - m
+                r.admitted_at = now
+                r.token_times.append(now)
+                r.prefix_hit_tokens = m
+                self.metrics["prefill_tokens"] += sfx
+                self.metrics["prefix_hit_tokens"] += m
+                self.events.append(("admit", r.rid, slot_i,
+                                    int(self.metrics["decode_steps"])))
+                rng = (np.random.default_rng([r.seed, r.rid])
+                       if not self.greedy and r.temperature > 0.0 else None)
+                slot = _Slot(req=r, next_token=0, produced=0, tokens=[],
+                             rng=rng, pos=len(r.prompt))
+                if rows is None:
+                    slot.next_token = int(ids[slot_i, sfx - 1])
+                else:
+                    slot.next_token = self._select_token(
+                        slot, rows[slot_i, sfx - 1])
+                slot.tokens.append(slot.next_token)
+                slot.produced = 1
+                if self.spec_k:
+                    slot.spec_k = self.spec_k
+                    slot.session = self.drafter.begin(
+                        [int(t) for t in r.prompt] + [slot.next_token],
+                        slot=slot_i, rid=r.rid)
+                if self.radix is not None and len(r.prompt) // page:
+                    # register this prompt's full pages; snapshot recurrent
+                    # state at each page boundary from the extend checkpoints
+                    # (checkpoint j = state after j suffix tokens, so the
+                    # page-p boundary sits at j = (p+1)*page - m)
+                    full = len(r.prompt) // page
+                    blocks = ([int(self._tables[slot_i, p])
+                               for p in range(full)]
+                              if self.allocator is not None else None)
+                    recs = []
+                    for p in range(full):
+                        j = (p + 1) * page - m
+                        recs.append({name: stk[j, :, slot_i].copy()
+                                     for name, stk in rec_np.items()}
+                                    if j >= 1 else None)
+                    self.radix.insert(r.prompt, len(r.prompt), blocks, recs)
+                if slot.produced >= r.max_new_tokens:   # 1-token request
+                    self._free_slot_pages(slot_i)
+                    self._retire(None, slot, done)
+                else:
+                    self._slots[slot_i] = slot
             return leftover
-
-        # one reset program: pos + recurrent snapshots for warm slots
-        # (rec keys are the state's recurrent fields — fixed per family,
-        # so the reset trace is reused across admissions)
-        slots_arr = np.full((b,), b, np.int32)     # sentinel rows drop
-        pos_vals = np.zeros((b,), np.int32)
-        rec: Dict[str, np.ndarray] = {}
-        for name in ("x_prev", "cm_prev", "wkv", "conv_tail", "ssm_h"):
-            leaf = getattr(self._state, name, None)
-            if leaf is not None:
-                rec[name] = np.zeros((leaf.shape[0], b) + tuple(
-                    leaf.shape[2:]), np.float32)
-        for j, (r, slot_i, m, nodes) in enumerate(plan):
-            slots_arr[j] = slot_i
-            pos_vals[j] = m
-            if m and rec:
-                snap = nodes[-1].rec
-                for name, arr in rec.items():
-                    arr[:, j] = snap[name]
-        self._state = self._reset(self._st(), slots_arr, pos_vals, rec)
-
-        # one extend program at the suffix bucket
-        bucket = self._bucket(max(len(r.prompt) - m
-                                  for r, _, m, _ in plan))
-        toks = np.zeros((b, bucket), np.int32)
-        adv = np.zeros((b,), np.int32)
-        for r, slot_i, m, _ in plan:
-            sfx = len(r.prompt) - m
-            toks[slot_i, :sfx] = r.prompt[m:]
-            adv[slot_i] = sfx
-        ids_dev, logits, self._state, rec_stack = self._extend(
-            self.params, self._st(), toks, adv)
-        ids = np.asarray(ids_dev)                         # (B, bucket)
-        rows = None
-        if not self.greedy and any(r.temperature > 0.0
-                                   for r, _, _, _ in plan):
-            rows = np.asarray(logits.astype(jnp.float32))  # (B, bkt, V)
-        rec_np = ({name: np.asarray(stk, np.float32)
-                   for name, stk in rec_stack.items()}
-                  if self.radix is not None else {})
-
-        for r, slot_i, m, nodes in plan:
-            sfx = len(r.prompt) - m
-            r.admitted_at = now
-            self._wait_sum += max(0.0, now - r.submitted_at)
-            self.metrics["prefill_tokens"] += sfx
-            self.metrics["prefix_hit_tokens"] += m
-            self.events.append(("admit", r.rid, slot_i,
-                                int(self.metrics["decode_steps"])))
-            rng = (np.random.default_rng([r.seed, r.rid])
-                   if not self.greedy and r.temperature > 0.0 else None)
-            slot = _Slot(req=r, next_token=0, produced=0, tokens=[],
-                         rng=rng, pos=len(r.prompt))
-            if rows is None:
-                slot.next_token = int(ids[slot_i, sfx - 1])
-            else:
-                slot.next_token = self._select_token(
-                    slot, rows[slot_i, sfx - 1])
-            slot.tokens.append(slot.next_token)
-            slot.produced = 1
-            if self.spec_k:
-                slot.spec_k = self.spec_k
-                slot.session = self.drafter.begin(
-                    [int(t) for t in r.prompt] + [slot.next_token],
-                    slot=slot_i, rid=r.rid)
-            if self.radix is not None and len(r.prompt) // page:
-                # register this prompt's full pages; snapshot recurrent
-                # state at each page boundary from the extend checkpoints
-                # (checkpoint j = state after j suffix tokens, so the
-                # page-p boundary sits at j = (p+1)*page - m)
-                full = len(r.prompt) // page
-                blocks = ([int(self._tables[slot_i, p])
-                           for p in range(full)]
-                          if self.allocator is not None else None)
-                recs = []
-                for p in range(full):
-                    j = (p + 1) * page - m
-                    recs.append({name: stk[j, :, slot_i].copy()
-                                 for name, stk in rec_np.items()}
-                                if j >= 1 else None)
-                self.radix.insert(r.prompt, len(r.prompt), blocks, recs)
-            if slot.produced >= r.max_new_tokens:   # 1-token request
-                self._free_slot_pages(slot_i)
-                self._retire(None, slot, done)
-            else:
-                self._slots[slot_i] = slot
-        return leftover
 
     def _prefill_args(self, group: List[Request], free: List[int]):
         """Bucket-pad an admission group into prefill arguments.
 
-        Returns ``(inputs, lengths, slots)`` — pure array construction,
-        shared by the inline admission path and the mesh engine's async
-        prefill workers (the arrays are what a worker thread hands to the
-        jitted prefill; ``slots`` drives the insert scatter afterwards).
+        Returns ``(inputs, lengths, slots)`` — array construction, shared
+        by the inline admission path and the mesh engine's async prefill
+        workers (the arrays are what a worker thread hands to the jitted
+        prefill; ``slots`` drives the insert scatter afterwards).  Counts
+        the ``max_batch x bucket`` positions the prefill will compute.
         """
         cfg = self.model.cfg
         b = self.max_batch
         bucket = self._bucket(max(len(r.prompt) for r in group))
+        self.metrics["prefill_positions"] += b * bucket
         if cfg.input_kind == "tokens":
             arr = np.zeros((b, bucket), np.int32)
         else:
@@ -1063,8 +1092,12 @@ class ServeEngine:
                done: List[Request]) -> None:
         """Prefill a bucket-padded admission group into free slots."""
         inputs, lengths, slots = self._prefill_args(group, free)
-        logits, sub = self._prefill(self.params, inputs, lengths)
-        self._finish_admit(group, free, logits, sub, slots, done)
+        with TraceAnnotation("serve.admit",
+                             step=int(self.metrics["decode_steps"]),
+                             rows=len(group),
+                             bucket=next(iter(inputs.values())).shape[1]):
+            logits, sub = self._prefill(self.params, inputs, lengths)
+            self._finish_admit(group, free, logits, sub, slots, done)
 
     def _finish_admit(self, group: List[Request], free: List[int],
                       logits, sub, slots: np.ndarray,
@@ -1082,7 +1115,7 @@ class ServeEngine:
         now = time.monotonic()
         for j, r in enumerate(group):
             r.admitted_at = now
-            self._wait_sum += max(0.0, now - r.submitted_at)
+            r.token_times.append(now)
             self.metrics["prefill_tokens"] += len(r.prompt)
             self.events.append(("admit", r.rid, free[j],
                                 int(self.metrics["decode_steps"])))
@@ -1121,11 +1154,13 @@ class ServeEngine:
         else:               # frame stubs decode over embedded tokens
             nb = {"frames": np.zeros((b, 1, cfg.d_model), np.float32)}
         if self.paged:      # this step writes each slot's position `pos`
-            for i in active:
-                self._ensure_pages(i, self._slots[i].pos)
+            with TraceAnnotation("serve.pages"):
+                for i in active:
+                    self._ensure_pages(i, self._slots[i].pos)
         logits, self._state = self._decode(self.params, self._st(), nb)
         ids, rows = self._pull_logits(
             logits, any(self._slots[i].rng is not None for i in active))
+        now = time.monotonic()
         self.metrics["decode_steps"] += 1
         self.metrics["decode_tokens"] += len(active)
         self._occ_num += len(active)
@@ -1136,6 +1171,7 @@ class ServeEngine:
             slot = self._slots[i]
             slot.next_token = self._next_token(slot, i, ids, rows)
             slot.tokens.append(slot.next_token)
+            slot.req.token_times.append(now)
             slot.produced += 1
             slot.pos += 1
             if slot.session is not None:
@@ -1260,15 +1296,18 @@ class ServeEngine:
             return
         emitted: Dict[int, List[int]] = {}
         if self.paged:      # the verify window writes pos..pos+k per slot
-            for i in active:
-                self._ensure_pages(i, self._slots[i].pos + k)
+            with TraceAnnotation("serve.pages"):
+                for i in active:
+                    self._ensure_pages(i, self._slots[i].pos + k)
         if self.greedy:
             # fused path: verify + longest-prefix accept + commit in one
             # dispatch; the host pulls (B, k+1) ids + (B,) advances
             ids_dev, adv_dev, self._state = self._verify_greedy(
                 self.params, self._st(), toks, caps)
-            ids = np.asarray(ids_dev)
-            adv = np.asarray(adv_dev)
+            with TraceAnnotation("serve.pull"):
+                ids = np.asarray(ids_dev)
+                adv = np.asarray(adv_dev)
+            now = time.monotonic()
             for i in active:
                 a = int(adv[i]) - 1
                 out = drafts[i][:a] + [int(ids[i, a])]
@@ -1281,9 +1320,12 @@ class ServeEngine:
             ids_dev, logits, self._state, rec = self._verify(
                 self.params, self._st(), toks)
             sampling = any(self._slots[i].rng is not None for i in active)
-            ids = np.asarray(ids_dev)                         # (B, k+1)
-            rows = (np.asarray(logits.astype(jnp.float32))    # (B, k+1, V)
+            with TraceAnnotation("serve.pull"):
+                ids = np.asarray(ids_dev)                     # (B, k+1)
+                rows = (np.asarray(
+                    logits.astype(jnp.float32))               # (B, k+1, V)
                     if sampling else None)
+            now = time.monotonic()
             advance = np.zeros((b,), np.int32)
             for i in active:
                 slot = self._slots[i]
@@ -1317,6 +1359,7 @@ class ServeEngine:
                 elif slot.spec_ewma > self._SPEC_HI:
                     slot.spec_k = min(self.spec_k_max, slot.spec_k + 1)
             slot.tokens.extend(out)
+            slot.req.token_times.extend([now] * len(out))
             slot.session.extend(out)
             slot.produced += len(out)
             slot.next_token = out[-1]
@@ -1556,126 +1599,128 @@ class ServeEngine:
         (nothing traced), and scatter pos + recurrent leaves through the
         one jitted ``slot_restore`` program.
         """
-        t_start = time.perf_counter()
-        b = self.max_batch
-        entries = [(r, self._parked[r.rid]) for r in group]
-        leftover: List[Request] = []
-        placed: List[tuple] = []
-        if self.paged:
-            free_iter = iter(free)
-            for r, e in entries:
-                new_ids: List[int] = []
-                if self.allocator is not None:
-                    n_used = (e.pos - 1) // self.page_size + 1
-                    dry = False
-                    for _ in range(n_used):
-                        blk = self._alloc_block()
-                        if blk is None:
-                            dry = True
-                            break
-                        new_ids.append(blk)
-                    if dry:           # roll back, requeue, keep going
-                        for blk in new_ids:
-                            self.allocator.free(blk)
-                        leftover.append(r)
-                        continue
-                slot_i = next(free_iter)
-                if self.allocator is not None:
-                    for p, blk in enumerate(new_ids):
-                        self._tables[slot_i, p] = blk
-                placed.append((r, e, slot_i, new_ids))
-            if placed and self.allocator is not None:
-                all_ids = np.concatenate(
-                    [np.asarray(ids, np.int32)
-                     for _, _, _, ids in placed])
-                updates: Dict[str, Any] = {}
-                for name in self._KV_LEAVES:
-                    tgt = getattr(self._state, name)
-                    if tgt is None:
-                        continue
-                    pgs = np.concatenate(
-                        [e.pages[name] for _, e, _, _ in placed], axis=1)
-                    updates[name] = tgt.at[:, all_ids].set(
-                        jnp.asarray(pgs, tgt.dtype))
-                self._state = self._state._replace(**updates)
-            if placed:
-                slots_arr = np.full((b,), b, np.int32)
-                pos_vals = np.zeros((b,), np.int32)
-                rec_names = [
-                    n for n in ("x_prev", "cm_prev", "wkv", "conv_tail",
-                                "ssm_h", "wkv_scale", "ssm_scale")
-                    if getattr(self._state, n, None) is not None]
-                rec = {n: np.zeros(
-                    (getattr(self._state, n).shape[0], b)
-                    + tuple(getattr(self._state, n).shape[2:]),
-                    getattr(self._state, n).dtype) for n in rec_names}
-                for g, (r, e, slot_i, _) in enumerate(placed):
-                    slots_arr[g] = slot_i
-                    pos_vals[g] = e.pos
-                    for n in rec_names:
-                        rec[n][:, g] = e.leaves[n]
-                self._state = self._slot_restore(self._st(), slots_arr,
-                                                 pos_vals, rec)
-        else:
-            state = self._state
-            max_pos = max(e.pos for _, e in entries)
-            cache_len = (state.cache_k.shape[2]
-                         if state.cache_k is not None else None)
-            bk = self._bucket(max_pos)
-            if cache_len is not None and bk < max_pos:
-                bk = cache_len     # non-pow2 max_seq tail: one-off shape
-            fields: Dict[str, Any] = {}
-            for name in state._fields:
-                leaf = getattr(state, name)
-                if leaf is None:
-                    fields[name] = None
-                elif name == "pos":
-                    fields[name] = np.zeros((b,), np.int32)
-                elif name in self._KV_LEAVES:
-                    fields[name] = np.zeros(
-                        (leaf.shape[0], b, bk) + tuple(leaf.shape[3:]),
-                        leaf.dtype)
-                else:
-                    fields[name] = np.zeros(
-                        (leaf.shape[0], b) + tuple(leaf.shape[2:]),
-                        leaf.dtype)
-            slots_arr = np.full((b,), b, np.int32)
-            for g, (r, e) in enumerate(entries):
-                slots_arr[g] = free[g]
-                fields["pos"][g] = e.pos
-                for name, arr in e.leaves.items():
-                    if name in self._KV_LEAVES:
-                        fields[name][:, g, :e.pos] = arr
+        with TraceAnnotation("serve.admit",
+                             step=int(self.metrics["decode_steps"]),
+                             rows=len(group)):
+            t_start = time.perf_counter()
+            b = self.max_batch
+            entries = [(r, self._parked[r.rid]) for r in group]
+            leftover: List[Request] = []
+            placed: List[tuple] = []
+            if self.paged:
+                free_iter = iter(free)
+                for r, e in entries:
+                    new_ids: List[int] = []
+                    if self.allocator is not None:
+                        n_used = (e.pos - 1) // self.page_size + 1
+                        dry = False
+                        for _ in range(n_used):
+                            blk = self._alloc_block()
+                            if blk is None:
+                                dry = True
+                                break
+                            new_ids.append(blk)
+                        if dry:           # roll back, requeue, keep going
+                            for blk in new_ids:
+                                self.allocator.free(blk)
+                            leftover.append(r)
+                            continue
+                    slot_i = next(free_iter)
+                    if self.allocator is not None:
+                        for p, blk in enumerate(new_ids):
+                            self._tables[slot_i, p] = blk
+                    placed.append((r, e, slot_i, new_ids))
+                if placed and self.allocator is not None:
+                    all_ids = np.concatenate(
+                        [np.asarray(ids, np.int32)
+                         for _, _, _, ids in placed])
+                    updates: Dict[str, Any] = {}
+                    for name in self._KV_LEAVES:
+                        tgt = getattr(self._state, name)
+                        if tgt is None:
+                            continue
+                        pgs = np.concatenate(
+                            [e.pages[name] for _, e, _, _ in placed], axis=1)
+                        updates[name] = tgt.at[:, all_ids].set(
+                            jnp.asarray(pgs, tgt.dtype))
+                    self._state = self._state._replace(**updates)
+                if placed:
+                    slots_arr = np.full((b,), b, np.int32)
+                    pos_vals = np.zeros((b,), np.int32)
+                    rec_names = [
+                        n for n in ("x_prev", "cm_prev", "wkv", "conv_tail",
+                                    "ssm_h", "wkv_scale", "ssm_scale")
+                        if getattr(self._state, n, None) is not None]
+                    rec = {n: np.zeros(
+                        (getattr(self._state, n).shape[0], b)
+                        + tuple(getattr(self._state, n).shape[2:]),
+                        getattr(self._state, n).dtype) for n in rec_names}
+                    for g, (r, e, slot_i, _) in enumerate(placed):
+                        slots_arr[g] = slot_i
+                        pos_vals[g] = e.pos
+                        for n in rec_names:
+                            rec[n][:, g] = e.leaves[n]
+                    self._state = self._slot_restore(self._st(), slots_arr,
+                                                     pos_vals, rec)
+            else:
+                state = self._state
+                max_pos = max(e.pos for _, e in entries)
+                cache_len = (state.cache_k.shape[2]
+                             if state.cache_k is not None else None)
+                bk = self._bucket(max_pos)
+                if cache_len is not None and bk < max_pos:
+                    bk = cache_len     # non-pow2 max_seq tail: one-off shape
+                fields: Dict[str, Any] = {}
+                for name in state._fields:
+                    leaf = getattr(state, name)
+                    if leaf is None:
+                        fields[name] = None
+                    elif name == "pos":
+                        fields[name] = np.zeros((b,), np.int32)
+                    elif name in self._KV_LEAVES:
+                        fields[name] = np.zeros(
+                            (leaf.shape[0], b, bk) + tuple(leaf.shape[3:]),
+                            leaf.dtype)
                     else:
-                        fields[name][:, g] = arr
-                placed.append((r, e, free[g], []))
-            sub = type(state)(**fields)
-            self._state = self._insert(self._state, sub, slots_arr)
+                        fields[name] = np.zeros(
+                            (leaf.shape[0], b) + tuple(leaf.shape[2:]),
+                            leaf.dtype)
+                slots_arr = np.full((b,), b, np.int32)
+                for g, (r, e) in enumerate(entries):
+                    slots_arr[g] = free[g]
+                    fields["pos"][g] = e.pos
+                    for name, arr in e.leaves.items():
+                        if name in self._KV_LEAVES:
+                            fields[name][:, g, :e.pos] = arr
+                        else:
+                            fields[name][:, g] = arr
+                    placed.append((r, e, free[g], []))
+                sub = type(state)(**fields)
+                self._state = self._insert(self._state, sub, slots_arr)
 
-        now = time.monotonic()
-        step = int(self.metrics["decode_steps"])
-        for r, e, slot_i, _ in placed:
-            r.admitted_at = now
-            self._wait_sum += max(0.0, now - r.submitted_at)
-            self.events.append(("restore", r.rid, slot_i, step))
-            rng = None
-            if e.rng_state is not None:
-                rng = np.random.default_rng()
-                rng.bit_generator.state = e.rng_state
-            slot = _Slot(req=r, next_token=e.next_token,
-                         produced=e.produced, tokens=list(e.tokens),
-                         rng=rng, pos=e.pos)
-            if self.spec_k:
-                slot.spec_k = self.spec_k
-                slot.session = self.drafter.begin(
-                    [int(t) for t in r.prompt] + slot.tokens[:1],
-                    slot=slot_i, rid=r.rid)
-                if len(slot.tokens) > 1:
-                    slot.session.extend(slot.tokens[1:])
-            self._slots[slot_i] = slot
-            del self._parked[r.rid]
-        self.metrics["restore_s"] += time.perf_counter() - t_start
-        return leftover
+            now = time.monotonic()
+            step = int(self.metrics["decode_steps"])
+            for r, e, slot_i, _ in placed:
+                r.admitted_at = now
+                self.events.append(("restore", r.rid, slot_i, step))
+                rng = None
+                if e.rng_state is not None:
+                    rng = np.random.default_rng()
+                    rng.bit_generator.state = e.rng_state
+                slot = _Slot(req=r, next_token=e.next_token,
+                             produced=e.produced, tokens=list(e.tokens),
+                             rng=rng, pos=e.pos)
+                if self.spec_k:
+                    slot.spec_k = self.spec_k
+                    slot.session = self.drafter.begin(
+                        [int(t) for t in r.prompt] + slot.tokens[:1],
+                        slot=slot_i, rid=r.rid)
+                    if len(slot.tokens) > 1:
+                        slot.session.extend(slot.tokens[1:])
+                self._slots[slot_i] = slot
+                del self._parked[r.rid]
+            self.metrics["restore_s"] += time.perf_counter() - t_start
+            return leftover
 
     # -- backpressure / fault injection -------------------------------------
 
@@ -1788,11 +1833,11 @@ class ServeEngine:
         # bench reads (a long inline prefill shows up as one huge gap)
         self.step_walls: List[float] = []
         self._occ_num = self._occ_den = 0
-        self._wait_sum = 0.0
-        self._n_done = 0
         t0 = time.monotonic()
         for r in requests:
             r.submitted_at = t0 + r.arrival_s
+            r.admit_started_at = None
+            r.token_times = []
         # pending = not yet arrived; waiting = arrived, unadmitted (the
         # bounded admission queue).  Instance attributes so a mid-trace
         # snapshot persists them alongside the slots.
@@ -1821,6 +1866,9 @@ class ServeEngine:
                 group.append(self._waiting.popleft())
             admitted_any = False
             if group:
+                now = time.monotonic()
+                for r in group:
+                    r.admit_started_at = now
                 parked = [r for r in group if r.rid in self._parked]
                 fresh = [r for r in group if r.rid not in self._parked]
                 nfree = free
@@ -1854,22 +1902,28 @@ class ServeEngine:
             if not active:
                 if self._admissions_inflight():
                     # nothing to decode until a prefill worker delivers
-                    time.sleep(0.0005)
+                    with TraceAnnotation("serve.idle"):
+                        time.sleep(0.0005)
                 elif self._pending and not self._waiting:
                     # idle: wait for the next arrival
-                    time.sleep(min(
-                        0.005,
-                        max(0.0, self._pending[0].arrival_s
-                            - (time.monotonic() - t0))))
+                    with TraceAnnotation("serve.idle"):
+                        time.sleep(min(
+                            0.005,
+                            max(0.0, self._pending[0].arrival_s
+                                - (time.monotonic() - t0))))
                 continue
 
-            if self.spec_k:
-                # speculative step: draft k per slot, verify k+1 at once,
-                # commit a variable 0..k+1 advance per slot (falls back to
-                # a plain step when no slot has anything worth verifying)
-                self._spec_step(active, done)
-            else:
-                self._plain_step(active, done)
+            with TraceAnnotation(
+                    "serve.decode",
+                    step=int(self.metrics["decode_steps"]) + 1):
+                if self.spec_k:
+                    # speculative step: draft k per slot, verify k+1 at
+                    # once, commit a variable 0..k+1 advance per slot
+                    # (falls back to a plain step when no slot has
+                    # anything worth verifying)
+                    self._spec_step(active, done)
+                else:
+                    self._plain_step(active, done)
             self.step_walls.append(time.monotonic())
             if self._admissions_inflight():
                 # a decode step ran while a prefill was still in flight —
@@ -1881,7 +1935,9 @@ class ServeEngine:
             self._tick()
 
         self.metrics["queue_depth"] = 0
-        self.metrics["queue_wait_s"] = self._wait_sum / max(self._n_done, 1)
+        waits = [r.admit_started_at - r.submitted_at for r in requests
+                 if r.admit_started_at is not None]
+        self.metrics["queue_wait_s"] = sum(waits) / max(len(waits), 1)
         self.metrics["slot_occupancy"] = self._occ_num / max(self._occ_den, 1)
         self.metrics["spec_acceptance"] = (
             self.metrics["draft_accepted"]

@@ -183,10 +183,12 @@ def test_metrics_and_no_drops(served):
     assert len(done) == len(reqs)
     for r in done:
         assert len(r.output) == r.max_new_tokens
-        assert r.admitted_at >= r.submitted_at
+        assert r.admitted_at >= r.admit_started_at >= r.submitted_at
         assert r.done_at >= r.admitted_at
     m = engine.metrics
     assert m["queue_wait_s"] >= 0.0
+    assert m["queue_wait_s"] == pytest.approx(np.mean(
+        [r.admit_started_at - r.submitted_at for r in done]))
     assert 0.0 < m["slot_occupancy"] <= 1.0
     assert m["decode_tokens"] + len(reqs) == sum(r.max_new_tokens
                                                  for r in reqs)
