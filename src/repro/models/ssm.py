@@ -134,7 +134,12 @@ def rwkv6_timemix(x: Array, p: Rwkv6Params, cfg: ArchConfig,
             ys = (S, out_t) if return_states else out_t
             return S, ys
 
-        S, ys_c = jax.lax.scan(step, S, (r_c, k_c, v_c, w_c))
+        # unrolled: as a loop, each step is 8 device ops on a v5e (its
+        # slices, the step, the output's update, the counter, the test),
+        # each an event in a profiler trace; unrolled, about 2, though a
+        # token takes about twice as long as looped
+        S, ys_c = jax.lax.scan(step, S, (r_c, k_c, v_c, w_c),
+                               unroll=chunk)
         return S, ys_c
 
     def to_chunks(a):  # (B,T,H,dk) -> (n_chunks, chunk, B, H, dk)
@@ -293,7 +298,7 @@ def mamba_mix(x: Array, p: MambaParams, cfg: ArchConfig,
             ys = (h, y_t) if return_states else y_t
             return h, ys
 
-        h, ys_c = jax.lax.scan(step, h, (dec_c, drv_c, c_c))
+        h, ys_c = jax.lax.scan(step, h, (dec_c, drv_c, c_c), unroll=chunk)
         return h, ys_c
 
     c_chunks = c_t.transpose(1, 0, 2).reshape(n_chunks, chunk, b, n)

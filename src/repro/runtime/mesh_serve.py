@@ -189,15 +189,14 @@ class MeshServeEngine(ServeEngine):
         if self._pool is None:
             super()._admit(group, free, done)
             return
-        inputs, lengths, slots = self._prefill_args(group, free)
-        taken = free[:len(group)]
-        self._reserved.update(taken)
-        for j, r in enumerate(group):
-            self.events.append(("prefill", r.rid, taken[j],
+        for r, slot_i in zip(group, free):
+            inputs, lengths, slots = self._prefill_args(r, slot_i)
+            self._reserved.add(slot_i)
+            self.events.append(("prefill", r.rid, slot_i,
                                 int(self.metrics["decode_steps"])))
-        fut: Future = self._pool.submit(self._prefill, self.params,
-                                        inputs, lengths)
-        self._inflight.append((group, free, slots, fut))
+            fut: Future = self._pool.submit(self._prefill, self.params,
+                                            inputs, lengths)
+            self._inflight.append(([r], [slot_i], slots, fut))
         self.metrics["async_prefills"] += len(group)
 
     def _poll_admissions(self, done: List[Request]) -> None:

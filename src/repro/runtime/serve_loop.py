@@ -12,13 +12,15 @@ Shapes are **bucketed** so the jit'd callables — and the tuned-block table
 keyed on kernel call shapes — are reused across admissions instead of
 retracing per batch composition:
 
-  * prefill:  (B = max_batch, S = next-pow2 prompt bucket), prompts
-    right-padded, true lengths passed to ``model.prefill(lengths=...)``
+  * prefill:  (B = 1, S = next-pow2 prompt bucket) for each admitted
+    request, its prompt right-padded and its true length passed to
+    ``model.prefill(lengths=...)``; so one trace per bucket serves every
+    admission group size, inline or on the mesh engine's prefill workers
   * decode:   (B = max_batch, 1) every step, against the fixed-shape slot
     state from ``model.init_slot_state`` (per-slot ``pos``)
-  * insert:   ``model.slot_update`` scatters a prefill's per-request state
-    (attention KV *and* rwkv/mamba recurrent state) into slot indices;
-    admission groups are padded with a sentinel slot that the scatter drops
+  * insert:   ``model.slot_update`` scatters a one-row prefill's state
+    (attention KV *and* rwkv/mamba recurrent state) into its slot, at the
+    prefill's (1, bucket) shape; a snapshot restore reuses it row by row
 
 Per-request outputs are bit-identical to single-stream decoding: the
 model-level seam masks pad steps out of recurrent state updates and each
@@ -337,8 +339,9 @@ class ServeMetrics:
 
     # token/step counters (accumulate over the engine lifetime);
     # prefill_positions counts the row-positions the prefill and extend
-    # programs computed (max_batch x bucket a call), so 1 - prefill_tokens
-    # / prefill_positions is the share of that work spent on padding
+    # programs computed (rows x bucket a call: one row a request on the
+    # prefill, max_batch on the paged extend), so 1 - prefill_tokens /
+    # prefill_positions is the share of that work spent on padding
     prefill_tokens: int = 0
     prefill_positions: int = 0
     decode_tokens: int = 0
@@ -769,10 +772,12 @@ class ServeEngine:
                         f"{self.allocator.num_blocks}; raise num_blocks")
 
     def _pull_logits(self, logits, sampling: bool):
-        """Host-side view of a step's logits: greedy needs only B ints
-        (device argmax); only steps where some live request actually
-        samples pull the full (B, vocab) float rows."""
-        b = self.max_batch
+        """Host-side view of a prefill's or step's logits, one row per
+        program row (a one-row prefill has one, decode ``max_batch``):
+        greedy needs only B ints (device argmax); only calls where some
+        live request actually samples pull the full (B, vocab) float
+        rows."""
+        b = logits.shape[0]
         with TraceAnnotation("serve.pull"):
             if self.greedy or not sampling:
                 return np.asarray(jnp.argmax(logits.reshape(b, -1),
@@ -1062,42 +1067,45 @@ class ServeEngine:
                     self._slots[slot_i] = slot
             return leftover
 
-    def _prefill_args(self, group: List[Request], free: List[int]):
-        """Bucket-pad an admission group into prefill arguments.
+    def _prefill_args(self, r: Request, slot_i: int):
+        """One request's prefill arguments: its prompt right-padded to
+        its bucket as one row, its true length, and its slot.
 
         Returns ``(inputs, lengths, slots)`` — array construction, shared
         by the inline admission path and the mesh engine's async prefill
         workers (the arrays are what a worker thread hands to the jitted
         prefill; ``slots`` drives the insert scatter afterwards).  Counts
-        the ``max_batch x bucket`` positions the prefill will compute.
+        the ``bucket`` positions the prefill will compute.
         """
         cfg = self.model.cfg
-        b = self.max_batch
-        bucket = self._bucket(max(len(r.prompt) for r in group))
-        self.metrics["prefill_positions"] += b * bucket
+        bucket = self._bucket(len(r.prompt))
+        self.metrics["prefill_positions"] += bucket
         if cfg.input_kind == "tokens":
-            arr = np.zeros((b, bucket), np.int32)
+            arr = np.zeros((1, bucket), np.int32)
         else:
-            arr = np.zeros((b, bucket, cfg.d_model), np.float32)
-        lengths = np.ones((b,), np.int32)       # dummy rows: length 1
-        slots = np.full((b,), b, np.int32)      # sentinel: scatter drops
-        for j, r in enumerate(group):
-            arr[j, :len(r.prompt)] = r.prompt
-            lengths[j] = len(r.prompt)
-            slots[j] = free[j]
+            arr = np.zeros((1, bucket, cfg.d_model), np.float32)
+        arr[0, :len(r.prompt)] = r.prompt
         key = "tokens" if cfg.input_kind == "tokens" else "frames"
-        return {key: arr}, lengths, slots
+        return ({key: arr}, np.array([len(r.prompt)], np.int32),
+                np.array([slot_i], np.int32))
 
     def _admit(self, group: List[Request], free: List[int],
                done: List[Request]) -> None:
-        """Prefill a bucket-padded admission group into free slots."""
-        inputs, lengths, slots = self._prefill_args(group, free)
-        with TraceAnnotation("serve.admit",
-                             step=int(self.metrics["decode_steps"]),
-                             rows=len(group),
-                             bucket=next(iter(inputs.values())).shape[1]):
-            logits, sub = self._prefill(self.params, inputs, lengths)
-            self._finish_admit(group, free, logits, sub, slots, done)
+        """Prefill each request of an admission group in its own one-row
+        call, in order, and scatter its state into its free slot.  A
+        request's admission starts when its own prefill does, and its
+        first token is stamped once the host holds it, before the next
+        request's prefill starts; no decode step runs between the calls,
+        and one trace per bucket serves every group size."""
+        for r, slot_i in zip(group, free):
+            r.admit_started_at = time.monotonic()
+            inputs, lengths, slots = self._prefill_args(r, slot_i)
+            with TraceAnnotation("serve.admit",
+                                 step=int(self.metrics["decode_steps"]),
+                                 rows=1,
+                                 bucket=next(iter(inputs.values())).shape[1]):
+                logits, sub = self._prefill(self.params, inputs, lengths)
+                self._finish_admit([r], [slot_i], logits, sub, slots, done)
 
     def _finish_admit(self, group: List[Request], free: List[int],
                       logits, sub, slots: np.ndarray,
@@ -1591,10 +1599,10 @@ class ServeEngine:
         """Re-admit parked (snapshot-restored) requests into free slots;
         returns requests deferred for lack of pool blocks (paged only).
 
-        Dense engines rebuild a bucket-padded sub-state from the stored
-        raw leaves and reuse the ``_insert`` scatter program (the same
-        trace a prefill admission of that bucket uses — restore never
-        retraces a warm engine).  Paged engines allocate fresh blocks,
+        Dense engines rebuild a one-row bucket-padded sub-state per
+        request from the stored raw leaves and reuse the ``_insert``
+        scatter program a one-row prefill of that bucket uses (restore
+        never retraces a warm engine).  Paged engines allocate fresh blocks,
         write the stored pages back with fixed-shape *eager* pool updates
         (nothing traced), and scatter pos + recurrent leaves through the
         one jitted ``slot_restore`` program.
@@ -1663,40 +1671,9 @@ class ServeEngine:
                     self._state = self._slot_restore(self._st(), slots_arr,
                                                      pos_vals, rec)
             else:
-                state = self._state
-                max_pos = max(e.pos for _, e in entries)
-                cache_len = (state.cache_k.shape[2]
-                             if state.cache_k is not None else None)
-                bk = self._bucket(max_pos)
-                if cache_len is not None and bk < max_pos:
-                    bk = cache_len     # non-pow2 max_seq tail: one-off shape
-                fields: Dict[str, Any] = {}
-                for name in state._fields:
-                    leaf = getattr(state, name)
-                    if leaf is None:
-                        fields[name] = None
-                    elif name == "pos":
-                        fields[name] = np.zeros((b,), np.int32)
-                    elif name in self._KV_LEAVES:
-                        fields[name] = np.zeros(
-                            (leaf.shape[0], b, bk) + tuple(leaf.shape[3:]),
-                            leaf.dtype)
-                    else:
-                        fields[name] = np.zeros(
-                            (leaf.shape[0], b) + tuple(leaf.shape[2:]),
-                            leaf.dtype)
-                slots_arr = np.full((b,), b, np.int32)
                 for g, (r, e) in enumerate(entries):
-                    slots_arr[g] = free[g]
-                    fields["pos"][g] = e.pos
-                    for name, arr in e.leaves.items():
-                        if name in self._KV_LEAVES:
-                            fields[name][:, g, :e.pos] = arr
-                        else:
-                            fields[name][:, g] = arr
+                    self._restore_row(e, free[g])
                     placed.append((r, e, free[g], []))
-                sub = type(state)(**fields)
-                self._state = self._insert(self._state, sub, slots_arr)
 
             now = time.monotonic()
             step = int(self.metrics["decode_steps"])
@@ -1721,6 +1698,38 @@ class ServeEngine:
                 del self._parked[r.rid]
             self.metrics["restore_s"] += time.perf_counter() - t_start
             return leftover
+
+    def _restore_row(self, e, slot_i: int) -> None:
+        """Scatter one parked request's stored leaves into ``slot_i``
+        through the ``_insert`` program: a one-row sub-state padded to
+        the bucket of its position, the shape a one-row prefill of that
+        bucket inserts, so a warm engine restores without a new trace."""
+        state = self._state
+        cache_len = (state.cache_k.shape[2]
+                     if state.cache_k is not None else None)
+        bk = self._bucket(e.pos)
+        if cache_len is not None and bk < e.pos:
+            bk = cache_len         # non-pow2 max_seq tail: one-off shape
+        fields: Dict[str, Any] = {}
+        for name in state._fields:
+            leaf = getattr(state, name)
+            if leaf is None:
+                fields[name] = None
+            elif name == "pos":
+                fields[name] = np.array([e.pos], np.int32)
+            elif name in self._KV_LEAVES:
+                fields[name] = np.zeros(
+                    (leaf.shape[0], 1, bk) + tuple(leaf.shape[3:]),
+                    leaf.dtype)
+                if name in e.leaves:
+                    fields[name][:, 0, :e.pos] = e.leaves[name]
+            else:
+                fields[name] = np.zeros(
+                    (leaf.shape[0], 1) + tuple(leaf.shape[2:]), leaf.dtype)
+                if name in e.leaves:
+                    fields[name][:, 0] = e.leaves[name]
+        self._state = self._insert(state, type(state)(**fields),
+                                   np.array([slot_i], np.int32))
 
     # -- backpressure / fault injection -------------------------------------
 

@@ -194,6 +194,32 @@ def test_restore_does_not_retrace(served, tmp_path):
     assert eng.trace_counts["insert"] >= 1
 
 
+def test_restore_inserts_one_row_per_request(served, tmp_path):
+    """A dense restore scatters each parked request as its own one-row
+    sub-state, the shape a one-row prefill of its bucket inserts, so
+    every insert the respawned engine makes has one row."""
+    cfg, model, params = served("glm4-9b")
+    rows = []
+
+    def factory(i):
+        eng = ServeEngine(model, params, ServeConfig(
+            max_batch=3, max_seq=MAX_SEQ, snapshot_dir=str(tmp_path),
+            snapshot_every=2, kill_at_step=5 if i == 0 else None))
+        inner = eng._insert
+
+        def recorded(st, sub, slots):
+            rows.append((i, len(slots)))
+            return inner(st, sub, slots)
+        eng._insert = recorded
+        return eng
+
+    sup = ServeSupervisor(factory)
+    sup.run(_requests(cfg))
+    assert sup.history[0].resumed_rids
+    assert any(i == 1 for i, _ in rows)
+    assert all(n == 1 for _, n in rows), rows
+
+
 def test_double_kill_two_recoveries(served, tmp_path):
     """Two injected deaths (the second on the respawned engine) still
     finish every request bit-identically."""

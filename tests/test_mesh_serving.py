@@ -133,6 +133,26 @@ class TestPrefillSplit:
         assert got == ref
         assert eng.metrics["async_prefills"] == 6
 
+    def test_workers_prefill_one_row_per_request(self, glm):
+        """Each admitted request gets its own one-row prefill future, so
+        the workers reach the same (1, bucket) shapes as the inline
+        path, whatever the group size."""
+        cfg, model, params = glm
+        eng = MeshServeEngine(model, params, ServeConfig(
+            max_batch=4, max_seq=64, num_shards=1, prefill_workers=2))
+        shapes = []
+        inner = eng._prefill
+
+        def recorded(p, inputs, lengths):
+            shapes.append(inputs["tokens"].shape)
+            return inner(p, inputs, lengths)
+        eng._prefill = recorded
+        reqs = _mk_requests(cfg, (5, 21, 9, 13), (3, 4, 2, 5))
+        eng.serve(reqs)
+        assert sorted(shapes) == sorted((1, eng._bucket(len(r.prompt)))
+                                        for r in reqs)
+        assert eng.metrics["async_prefills"] == 4
+
     def test_decode_does_not_block_on_long_prompt(self, glm):
         """The split's whole point: with a slow prefill in flight, decode
         steps keep landing between the prefill submit and its admit."""

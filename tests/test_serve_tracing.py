@@ -8,8 +8,9 @@ The contract under test (see ``runtime/serve_loop.py``):
     the first token is stamped after its admission's program on both the
     dense and the paged path, and ``queue_wait_s`` is the mean of
     ``admit_started_at - submitted_at`` on every one of them
-  * ``prefill_positions`` counts ``max_batch x bucket`` per prefill or
-    extend call; ``Request.prefix_hit_tokens`` sums to the engine's count
+  * ``prefill_positions`` counts ``rows x bucket`` per call: one row a
+    request on the dense prefill, ``max_batch`` rows per paged extend;
+    ``Request.prefix_hit_tokens`` sums to the engine's count
   * the ``serve.*`` host spans open with the expected names, nesting and
     ``step`` arguments (a recorder stands in for ``TraceAnnotation``),
     and a real ``jax.profiler`` trace holds them with their arguments
@@ -166,17 +167,18 @@ def test_prefill_positions_count_every_call(served, kind, monkeypatch):
     monkeypatch.setattr(serve_loop, "TraceAnnotation", rec)
     reqs = _requests(cfg)
     engine.serve(reqs)
-    calls = [s["args"]["bucket"] for s in rec.named("serve.admit")]
-    assert calls and engine.metrics["prefill_positions"] == \
-        MAX_BATCH * sum(calls)
-    if kind != "paged":      # dense: each call's bucket fits its longest
+    admits = rec.named("serve.admit")
+    calls = [s["args"]["bucket"] for s in admits]
+    if kind == "paged":      # one extend over every slot row a call
+        rows = [MAX_BATCH] * len(calls)
+    else:                    # one row a request, bucket fitted to it
+        rows = [s["args"]["rows"] for s in admits]
+        assert rows == [1] * len(reqs)
         lens = {r.rid: len(r.prompt) for r in reqs}
-        groups = collections.defaultdict(list)
-        for ev in engine.events:
-            if ev[0] == "admit":
-                groups[ev[3]].append(lens[ev[1]])
-        assert sorted(calls) == sorted(engine._bucket(max(g))
-                                       for g in groups.values())
+        assert calls == [engine._bucket(lens[ev[1]])
+                         for ev in engine.events if ev[0] == "admit"]
+    assert calls and engine.metrics["prefill_positions"] == \
+        sum(r * b for r, b in zip(rows, calls))
     pad = 1 - engine.metrics["prefill_tokens"] / \
         engine.metrics["prefill_positions"]
     assert 0 < pad < 1
@@ -213,11 +215,13 @@ def test_spans_names_nesting_and_steps(served, kind, monkeypatch):
     assert steps == list(range(d0 + 1,
                                int(engine.metrics["decode_steps"]) + 1))
     # each admission's step and rows match the events, which carry rids
+    # (a dense group opens one one-row span per request, at one step)
     admits = collections.Counter(ev[3] for ev in engine.events
                                  if ev[0] == "admit")
-    spans = {s["args"]["step"]: s["args"]["rows"]
-             for s in rec.named("serve.admit")}
-    assert spans == dict(admits)
+    spans = collections.Counter()
+    for s in rec.named("serve.admit"):
+        spans[s["args"]["step"]] += s["args"]["rows"]
+    assert spans == admits
 
 
 def test_real_profiler_trace_holds_the_spans(served, tmp_path):

@@ -120,6 +120,61 @@ def test_bucket_reuse_no_retrace(served):
 
 
 @pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b"])
+def test_group_prefills_one_row_per_request(served, arch):
+    """An admission group of k requests makes k one-row prefill calls,
+    and each request's greedy output, first token included, equals the
+    same request served alone."""
+    cfg, model, params = served(arch)
+    engine = ServeEngine(model, params, max_batch=4, max_seq=MAX_SEQ)
+    shapes = []
+    prefill = engine._prefill
+
+    def recorded(p, inputs, lengths):
+        shapes.append(inputs["tokens"].shape)
+        return prefill(p, inputs, lengths)
+    engine._prefill = recorded
+    reqs = _mixed_requests(cfg, lens=[5, 20, 9, 14], max_news=[3, 6, 2, 5],
+                           seed=4)
+    done = engine.serve(reqs)
+    assert shapes == [(1, engine._bucket(len(r.prompt))) for r in reqs]
+    admits = [ev[3] for ev in engine.events if ev[0] == "admit"]
+    assert admits == [admits[0]] * len(reqs), "one group, no step between"
+    # each request's admission starts with its own prefill, after the
+    # first token of the request before it in the group
+    order = [ev[1] for ev in engine.events if ev[0] == "admit"]
+    by_rid = {r.rid: r for r in done}
+    for a, b in zip(order, order[1:]):
+        assert by_rid[b].admit_started_at >= by_rid[a].admitted_at
+    alone = ServeEngine(model, params, max_batch=4, max_seq=MAX_SEQ)
+    for r in done:
+        solo = Request(r.rid, r.prompt, max_new_tokens=r.max_new_tokens)
+        (ref,) = alone.serve([solo])
+        assert list(r.output) == list(ref.output), (arch, r.rid)
+        assert list(r.output) == _single_stream(
+            model, params, r.prompt, r.max_new_tokens), (arch, r.rid)
+
+
+@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b"])
+def test_one_request_per_bucket_warms_every_group_size(served, arch):
+    """Once one request per prompt bucket has been served alone, serving
+    admission groups of every size from 1 to max_batch traces nothing
+    new: warming the buckets warms every shape a window can reach."""
+    cfg, model, params = served(arch)
+    engine = ServeEngine(model, params, max_batch=4, max_seq=MAX_SEQ,
+                         min_bucket=16)
+    for i, n in enumerate((16, 32, 60)):            # buckets 16, 32, 64
+        engine.serve(_mixed_requests(cfg, lens=[n], max_news=[2],
+                                     seed=10 + i))
+    warm = dict(engine.trace_counts)
+    assert warm["prefill"] == 3 and warm["decode"] == 1
+    lens = [3, 40, 17, 9]
+    for k in range(1, engine.max_batch + 1):
+        engine.serve(_mixed_requests(cfg, lens=lens[:k], max_news=[3] * k,
+                                     seed=20 + k))
+        assert dict(engine.trace_counts) == warm, f"group of {k} retraced"
+
+
+@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b"])
 def test_pad_correctness_mixed_lengths(served, arch):
     """Bucket-padded prefill with true lengths is bit-identical to the
     unpadded per-request prefill — logits and carried decode state."""

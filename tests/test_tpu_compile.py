@@ -13,6 +13,7 @@ workers each import every test file.
 """
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -158,3 +159,46 @@ def test_glm4_decode_step_fits_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 2 * 204e6 * 2   # the weights are in
     assert total < V5E_HBM_BYTES, total
+
+
+_BOOKKEEPING = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                "constant"}
+
+
+def _loop_body_sizes(hlo: str):
+    """Device ops (bookkeeping left out) in each while loop's body of a
+    compiled module's text."""
+    ops, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            ops[name] = []
+        elif name and line.startswith("  ") and " = " in line:
+            op = re.search(r"\s([a-z][a-z0-9\-]*)\((?:%|\))",
+                           line.split(" = ", 1)[1])
+            if op and op.group(1) not in _BOOKKEEPING:
+                ops[name].append(op.group(1))
+    bodies = re.findall(r" while\(.*?body=%([\w.\-]+)", hlo)
+    return [len(ops[b]) for b in bodies]
+
+
+def test_rwkv6_prefill_runs_no_loop_a_token(one_chip):
+    """A one-row rwkv6-3b prefill (published widths, 2 of its 32 layers,
+    a 256-token bucket) compiles its wkv scan with no device loop a
+    token: each loop left (layers, 64-token chunks) holds at least a
+    chunk's worth of ops.  A loop a token costs 8 device ops a token and
+    layer (its slices, step, update, counter and test), and a profiler
+    trace of a serving window holds every one of them."""
+    cfg = get_arch("rwkv6-3b").scaled(n_layers=2)
+    model = build_model(cfg)
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          model.abstract_params())
+    tokens = _sds((1, 256), jnp.int32, one_chip)
+    lengths = _sds((1,), jnp.int32, one_chip)
+    hlo = jax.jit(
+        lambda p, t, n: model.prefill(p, {"tokens": t}, headroom=0,
+                                      lengths=n)
+    ).lower(params, tokens, lengths).compile().as_text()
+    sizes = _loop_body_sizes(hlo)
+    assert sizes and min(sizes) >= 64, sizes
