@@ -59,6 +59,11 @@ def _dtype_bytes(cfg: ArchConfig) -> int:
     return 2 if cfg.dtype == "bfloat16" else 4
 
 
+def _mamba2_widths(cfg: ArchConfig) -> Tuple[int, int]:
+    """(inner width, B or C width) of a Mamba-2 mixer."""
+    return cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+
+
 def param_count(cfg: ArchConfig) -> Tuple[float, float]:
     """(total, active) parameter counts."""
     D, F, L, V = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.vocab_size
@@ -82,7 +87,10 @@ def param_count(cfg: ArchConfig) -> Tuple[float, float]:
         per_layer += 5 * D * D          # wr wk wv wg wo
         per_layer += 2 * D * F + D * D  # channel mix
         per_layer += 2 * 64 * D         # decay lora
-    if cfg.family == "hybrid":
+    if cfg.family == "hybrid" and cfg.ssm_heads:
+        di, gn = _mamba2_widths(cfg)
+        per_layer += D * (2 * di + 2 * gn + cfg.ssm_heads) + di * D  # mamba-2
+    elif cfg.family == "hybrid":
         per_layer += 2 * D * D + D * (2 * cfg.ssm_state + 1) + D * D  # mamba
     total = n + L * per_layer
     active = total
@@ -152,7 +160,14 @@ def step_costs(cfg: ArchConfig, shape: ShapeConfig, mesh: MeshSpec,
         bk["rwkv_rec"] = 8 * T * D * dh * L * bwd_mult
         bk["rwkv_cm"] = (2 * 2 * T * D * _eff(F, tp) +
                          2 * T * D * _eff(D, tp)) * L * bwd_mult
-    if cfg.family == "hybrid":
+    if cfg.family == "hybrid" and cfg.ssm_heads:
+        di, gn = _mamba2_widths(cfg)
+        N = cfg.ssm_state
+        bk["mamba"] = ((2 * T * D * _eff(2 * di + 2 * gn + cfg.ssm_heads, tp))
+                       + (2 * T * cfg.ssm_conv * (di + 2 * gn))
+                       + (6 * T * di * N)
+                       + (2 * T * _eff(di, tp) * D)) * L * bwd_mult
+    elif cfg.family == "hybrid":
         N = cfg.ssm_state
         bk["mamba"] = ((2 * T * D * _eff(2 * D, tp)) +
                        (2 * T * cfg.ssm_conv * D) +
@@ -191,8 +206,10 @@ def step_costs(cfg: ArchConfig, shape: ShapeConfig, mesh: MeshSpec,
         hbm += kv_dev  # full cache streamed once per decoded token
         bk["kv_cache_bytes"] = kv_dev
     if cfg.family in ("ssm", "hybrid") and shape.is_decode:
-        hbm += L * _eff(B, dp) * (Hq * dh * dh if cfg.family == "ssm"
-                                  else D * cfg.ssm_state) * 4
+        width = (Hq * dh * dh if cfg.family == "ssm"
+                 else _mamba2_widths(cfg)[0] * cfg.ssm_state if cfg.ssm_heads
+                 else D * cfg.ssm_state)
+        hbm += L * _eff(B, dp) * width * 4
 
     # ---- collectives ---------------------------------------------------------
     coll = 0.0

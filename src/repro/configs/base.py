@@ -139,6 +139,16 @@ class ArchConfig:
     # SSM / hybrid
     ssm_state: int = 0
     ssm_conv: int = 4
+    # Mamba-2 mixer (Falcon-H1): ssm_heads > 0 selects it over hymba's
+    # Mamba-1 branch, with inner width ssm_heads * ssm_head_dim, B/C shared
+    # by ssm_groups groups of heads, and a chunked SSD scan in prefill
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    # how a hybrid block joins its attention and SSM branches:
+    # "norm_mean" (hymba: RMSNorm each branch, then average) | "sum"
+    hybrid_fusion: str = "norm_mean"
     sliding_window: int = 0        # hybrid local-attention window
     global_attn_every: int = 0     # hybrid: every k-th layer is global
     # modality stub ("tokens" | "frames")
@@ -228,6 +238,9 @@ class ArchConfig:
             "d_ff": self.d_ff, "vocab_size": self.vocab_size,
             "head_dim": self.head_dim_, "rope_theta": self.rope_theta,
             "ssm_state": self.ssm_state, "ssm_conv": self.ssm_conv,
+            "ssm_heads": self.ssm_heads, "ssm_head_dim": self.ssm_head_dim,
+            "ssm_groups": self.ssm_groups, "ssm_chunk": self.ssm_chunk,
+            "hybrid_fusion": self.hybrid_fusion,
             "sliding_window": self.sliding_window,
             "global_attn_every": self.global_attn_every,
             "n_experts": self.n_experts, "top_k": self.top_k,
@@ -257,6 +270,10 @@ class ArchConfig:
                       capacity_factor=2.0)
         if self.ssm_state:
             kw.update(ssm_state=8)
+        if self.ssm_heads:
+            # two groups of two heads, and prompts of a few chunks
+            kw.update(ssm_heads=4, ssm_head_dim=8, ssm_groups=2,
+                      ssm_chunk=8)
         if self.n_codebooks:
             kw.update(n_codebooks=2)
         kw["attn_chunk"] = 16

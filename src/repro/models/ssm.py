@@ -1,11 +1,21 @@
-"""Attention-free sequence mixers: RWKV6 (Finch) and a Mamba-style
-selective SSM (the hybrid branch of hymba).
+"""Attention-free sequence mixers: RWKV6 (Finch), a Mamba-style
+selective SSM (the hybrid branch of hymba) and the Mamba-2 mixer
+(Falcon-H1's SSM heads).
 
-Both are implemented as chunked scans: an outer ``lax.scan`` over time
-chunks carries the recurrent state (which is also exactly the decode-time
-state — long_500k decode is O(1) per step), and the inner chunk is a short
-unrolled recurrence.  Sequence length therefore never enters the memory
-footprint beyond one chunk of activations.
+Every mixer carries a fixed-size recurrent state, which is also exactly
+the decode-time state (long_500k decode is O(1) per step), and an outer
+``lax.scan`` over time chunks carries it through a prompt, so sequence
+length never enters the memory footprint beyond one chunk.  The chunk
+itself is computed in one of two forms:
+
+* a step-by-step recurrence, unrolled inside the chunk (RWKV6, Mamba-1):
+  their state decays per channel, which has no cheap matrix form;
+* the chunked SSD form (Mamba-2): its decay is one scalar per head and
+  step, so a chunk's outputs are matrix products (C B^T masked by the
+  decays, times dt x) plus the carried state read through C, and the
+  state is carried from one chunk boundary to the next.  Decode, and
+  speculative verify (which needs the state after every step), run the
+  same one-step recurrence instead.
 """
 from __future__ import annotations
 
@@ -228,10 +238,9 @@ def mamba_mix(x: Array, p: MambaParams, cfg: ArchConfig,
     di = p.conv_w.shape[1]
 
     xz = L.dense(x, p.w_in, pol)
-    # keep the mamba branch in the residual stream's (batch, seq) layout —
-    # without this XLA reshards (B,T,2D) between the mlp- and seq-sharded
-    # layouts every layer (hymba's 18x collective inflation, see
-    # EXPERIMENTS.md #Perf)
+    # keep the mamba branch in the residual stream's (batch, seq) layout:
+    # left free, XLA moves (B,T,2D) between the mlp- and seq-sharded
+    # layouts in every layer, a collective the size of the activations
     xz = constrain(xz, ("batch", "seq", None))
     xi, z = jnp.split(xz, 2, axis=-1)                # (B,T,Di)
 
@@ -313,4 +322,190 @@ def mamba_mix(x: Array, p: MambaParams, cfg: ArchConfig,
         h_steps = jnp.moveaxis(h_steps.reshape((t,) + h_steps.shape[2:]),
                                0, 1)                 # (B,T,Di,N)
         return out + ((step_tails(), h_steps),)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 (Falcon-H1's SSM heads): scalar decay per head, grouped B/C
+# ---------------------------------------------------------------------------
+
+class Mamba2Params(NamedTuple):
+    # in_proj by column block (z | x | B | C | dt), each block a leaf so a
+    # checkpoint's per-block multipliers fold into it
+    w_z: Array       # (D, H*P) gate
+    w_x: Array       # (D, H*P)
+    w_b: Array       # (D, G*N)
+    w_c: Array       # (D, G*N)
+    w_dt: Array      # (D, H)
+    conv_w: Array    # (K, H*P + 2*G*N) depthwise causal conv over x|B|C
+    conv_b: Array    # (H*P + 2*G*N,)
+    dt_bias: Array   # (H,)
+    a_log: Array     # (H,)  A = -exp(a_log)
+    d_skip: Array    # (H,)
+    norm: Array      # (H*P,) gated RMSNorm gain
+    w_out: Array     # (H*P, D)
+
+
+def ssm_state_shapes(cfg: ArchConfig) -> Tuple[tuple, tuple]:
+    """Per-row shapes of a hybrid layer's (conv tail, SSM state):
+    Mamba-2 ``((K-1, H*P + 2*G*N), (H, P, N))``, hymba's Mamba
+    ``((K-1, D), (D, N))``."""
+    k, n = cfg.ssm_conv, cfg.ssm_state
+    if cfg.ssm_heads:
+        di = cfg.ssm_heads * cfg.ssm_head_dim
+        return ((k - 1, di + 2 * cfg.ssm_groups * n),
+                (cfg.ssm_heads, cfg.ssm_head_dim, n))
+    return (k - 1, cfg.d_model), (cfg.d_model, n)
+
+
+_HI = jax.lax.Precision.HIGHEST   # float32 state products stay float32
+
+
+def _ssd_step(S: Array, x: Array, dt: Array, b: Array, c: Array,
+              a: Array) -> Tuple[Array, Array]:
+    """One recurrence step.  S (B, G, R, P, N) float32 (R heads a group);
+    x (B, G, R, P), dt (B, G, R), b/c (B, G, N), a (G, R).
+    S <- exp(dt a) S + dt x b^T;  y = S c."""
+    decay = jnp.exp(dt * a)[..., None, None]
+    S = decay * S + (dt[..., None] * x)[..., None] * b[:, :, None, None, :]
+    y = jnp.einsum("bgrpn,bgn->bgrp", S, c, precision=_HI)
+    return S, y
+
+
+def _ssd_chunks(S: Array, x: Array, dt: Array, b: Array, c: Array,
+                a: Array, chunk: int) -> Tuple[Array, Array]:
+    """The chunked SSD scan.  x (B, T, G, R, P), dt (B, T, G, R), b/c
+    (B, T, G, N), T a multiple of ``chunk``; S (B, G, R, P, N) float32.
+    Returns (S after T steps, y (B, T, G, R, P)).
+
+    Within a chunk, with cs the running sum of dt a from its start:
+      y_t = sum_{s<=t} (c_t . b_s) exp(cs_t - cs_s) dt_s x_s
+            + exp(cs_t) S_0 c_t
+      S_Q = exp(cs_Q) S_0 + sum_s exp(cs_Q - cs_s) dt_s x_s b_s^T
+    as matrix products; a ``lax.scan`` carries S across chunks."""
+    bsz, t = x.shape[:2]
+    n_chunks = t // chunk
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def to_chunks(arr):     # (B, T, ...) -> (n_chunks, B, chunk, ...)
+        arr = arr.reshape((bsz, n_chunks, chunk) + arr.shape[2:])
+        return jnp.moveaxis(arr, 1, 0)
+
+    def body(S, xs):
+        x_c, dt_c, b_c, c_c = xs
+        cs = jnp.cumsum(dt_c * a, axis=1)                    # (B,Q,G,R)
+        seg = cs[:, :, None] - cs[:, None, :]                # (B,Qt,Qs,G,R)
+        seg = jnp.where(tri[None, :, :, None, None], seg, -jnp.inf)
+        cb = jnp.einsum("btgn,bsgn->btsg", c_c, b_c, precision=_HI)
+        u = dt_c[..., None] * x_c                            # (B,Q,G,R,P)
+        y = jnp.einsum("btsgr,bsgrp->btgrp", cb[..., None] * jnp.exp(seg),
+                       u, precision=_HI)
+        y = y + jnp.exp(cs)[..., None] * jnp.einsum(
+            "btgn,bgrpn->btgrp", c_c, S, precision=_HI)
+        tail = jnp.exp(cs[:, -1:] - cs)[..., None] * u       # (B,Q,G,R,P)
+        S = (jnp.exp(cs[:, -1])[..., None, None] * S
+             + jnp.einsum("bsgrp,bsgn->bgrpn", tail, b_c, precision=_HI))
+        return S, y
+
+    S, y = jax.lax.scan(body, S, tuple(to_chunks(v) for v in (x, dt, b, c)))
+    return S, jnp.moveaxis(y, 0, 1).reshape(x.shape)
+
+
+@jax.named_scope("time_mix")
+def mamba2_mix(x: Array, p: Mamba2Params, cfg: ArchConfig,
+               pol: ExecutionPolicy, state: Tuple[Array, Array],
+               mask: Array = None, lengths: Array = None,
+               return_states: bool = False):
+    """x: (B,T,D).  state = (conv tail (B, K-1, H*P + 2*G*N), S (B, H, P,
+    N) float32).  Per head h of group g = h // (H/G):
+
+        xBC <- SiLU(conv_K(x|B|C) + bias);  dt = softplus(dt + dt_bias)
+        S <- exp(dt A_h) S + dt x B_g^T;    y = S C_g + D_h x
+
+    then y * SiLU(z), RMS-normed over each group's H*P/G channels, times
+    its gain, and the out projection.
+
+    ``mask``/``lengths`` as in :func:`mamba_mix`: pad steps have dt 0, so
+    they leave S exactly as it was, and the conv tail is each row's last
+    K-1 real inputs.  A prompt runs the chunked SSD scan
+    (:func:`_ssd_chunks`, chunks of ``cfg.ssm_chunk`` aligned to position
+    0, so a padded and an unpadded prompt chunk alike); ``T == 1`` is the
+    one-step recurrence, and ``return_states`` appends ``(tails (B,T,K-1,
+    C), states (B,T,H,P,N))``, the state after every step, by the same
+    recurrence in a ``lax.scan`` over the steps (speculative verify and
+    the paged path's extend).  Those states are float32, H*P*N*4 bytes a
+    step and row (4 MB at Falcon-H1's widths), so at published widths
+    only verify's short windows fit, not a whole prompt.
+
+    The mixer runs under the named scope ``time_mix``, its state
+    recurrence under ``wkv``: the scopes a profile reads for any
+    recurrent mixer and its recurrence.
+    """
+    b, t, _ = x.shape
+    h, hp, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    g = cfg.ssm_groups
+    r, di, gn = h // g, h * hp, g * n
+    kk = cfg.ssm_conv
+    conv_tail, s0 = state
+
+    z = L.dense(x, p.w_z, pol)
+    xbc = jnp.concatenate([L.dense(x, p.w_x, pol), L.dense(x, p.w_b, pol),
+                           L.dense(x, p.w_c, pol)], axis=-1)
+    dt_in = L.dense(x, p.w_dt, pol)
+
+    # depthwise causal conv over x|B|C through the carried tail
+    pad = jnp.concatenate([conv_tail.astype(xbc.dtype), xbc], axis=1)
+    pad32 = pad.astype(jnp.float32)
+    conv = sum(pad32[:, i:i + t] * p.conv_w[i].astype(jnp.float32)
+               for i in range(kk)) + p.conv_b.astype(jnp.float32)
+    conv = jax.nn.silu(conv)
+    if lengths is None:
+        new_tail = pad[:, t:t + kk - 1]
+    else:   # each row's last K-1 *real* inputs: pad cols [n, n + K - 1)
+        idx = lengths.astype(jnp.int32)[:, None] + jnp.arange(kk - 1)[None]
+        new_tail = jnp.take_along_axis(pad, idx[..., None], axis=1)
+
+    xs = conv[..., :di].reshape(b, t, g, r, hp)
+    bb = conv[..., di:di + gn].reshape(b, t, g, n)
+    cc = conv[..., di + gn:].reshape(b, t, g, n)
+    dt = jax.nn.softplus(dt_in.astype(jnp.float32)
+                         + p.dt_bias.astype(jnp.float32)).reshape(b, t, g, r)
+    if mask is not None:
+        dt = jnp.where(mask[:, :, None, None], dt, 0.0)
+    a = -jnp.exp(p.a_log.astype(jnp.float32)).reshape(g, r)
+    S = s0.astype(jnp.float32).reshape(b, g, r, hp, n)
+
+    steps = None
+    with jax.named_scope("wkv"):
+        if t == 1:
+            S, y = _ssd_step(S, xs[:, 0], dt[:, 0], bb[:, 0], cc[:, 0], a)
+            y = y[:, None]
+            steps = S[:, None]
+        elif return_states:
+            def step(S, u):
+                S, y_j = _ssd_step(S, *u, a)
+                return S, (y_j, S)
+
+            S, (y, steps) = jax.lax.scan(step, S, tuple(
+                jnp.moveaxis(v, 1, 0) for v in (xs, dt, bb, cc)))
+            y, steps = jnp.moveaxis(y, 0, 1), jnp.moveaxis(steps, 0, 1)
+        else:
+            q = cfg.ssm_chunk
+            tp = -(-t // q) * q
+            ext = ((0, 0), (0, tp - t))
+            S, y = _ssd_chunks(
+                S, *(jnp.pad(v, ext + ((0, 0),) * (v.ndim - 2))
+                     for v in (xs, dt, bb, cc)), a, q)
+            y = y[:, :t]
+    y = y + p.d_skip.astype(jnp.float32).reshape(g, r)[..., None] * xs
+    # gated RMSNorm (norm after gate), over each group's channels
+    y = y.reshape(b, t, g, di // g) * jax.nn.silu(
+        z.astype(jnp.float32)).reshape(b, t, g, di // g)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
+    y = (y.reshape(b, t, di) * p.norm.astype(jnp.float32)).astype(x.dtype)
+    out = L.dense(y, p.w_out, pol), (new_tail, S.reshape(b, h, hp, n))
+    if return_states:   # the tail after step j: pad cols [j+1, j+K)
+        idx = jnp.arange(t)[:, None] + jnp.arange(1, kk)[None]
+        tails = jnp.take(pad, idx, axis=1)
+        return out + ((tails, steps.reshape(b, t, h, hp, n)),)
     return out

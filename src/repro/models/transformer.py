@@ -4,8 +4,10 @@ One blocks-scanned decoder whose per-layer mixer is selected by the family:
   dense|audio|vlm : GQA attention
   moe             : GQA attention + (dense residual?) MoE FFN
   ssm             : RWKV6 time-mix + channel-mix (attention-free)
-  hybrid          : parallel GQA-attention + Mamba heads (hymba), fused by
-                    per-branch normalisation then mean
+  hybrid          : parallel GQA-attention + SSM heads on one normed input:
+                    hymba's Mamba, fused by per-branch normalisation then
+                    mean, or Falcon-H1's Mamba-2, summed into the residual
+                    (``cfg.ssm_heads`` and ``cfg.hybrid_fusion``)
 
 Layers are stacked along a leading "layers" axis and executed with
 ``jax.lax.scan`` so the 40-48 layer production configs compile as a single
@@ -131,19 +133,40 @@ def params_spec(cfg: ArchConfig) -> Dict[str, Any]:
         del blk["ln2"]  # channel-mix has its own pre-norm
         blk["ln2"] = ly(D, axes=("embed",), init="ones")
     if cfg.family == "hybrid":
-        Di = D  # mamba inner width = d_model (hymba parallel heads)
-        N = cfg.ssm_state
-        blk["mamba"] = {
-            "w_in": ly(D, 2 * Di, axes=("embed", "mlp"), init="scaled"),
-            "conv_w": ly(cfg.ssm_conv, Di, axes=(None, "embed"),
-                         init="scaled"),
-            "w_bc": ly(Di, 2 * N + 1, axes=("embed", None), init="scaled"),
-            "a_log": ly(Di, N, axes=("embed", "state"), init="zeros"),
-            "d_skip": ly(Di, axes=("embed",), init="ones"),
-            "w_out": ly(Di, D, axes=("mlp", "embed"), init="scaled"),
-        }
-        blk["norm_attn"] = ly(dh * cfg.n_heads, axes=("heads",), init="ones")
-        blk["norm_ssm"] = ly(D, axes=("embed",), init="ones")
+        if cfg.ssm_heads:
+            H, N = cfg.ssm_heads, cfg.ssm_state
+            Di, GN = H * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+            blk["mamba2"] = {
+                "w_z": ly(D, Di, axes=("embed", "mlp"), init="scaled"),
+                "w_x": ly(D, Di, axes=("embed", "mlp"), init="scaled"),
+                "w_b": ly(D, GN, axes=("embed", None), init="scaled"),
+                "w_c": ly(D, GN, axes=("embed", None), init="scaled"),
+                "w_dt": ly(D, H, axes=("embed", None), init="scaled"),
+                "conv_w": ly(cfg.ssm_conv, Di + 2 * GN, axes=(None, None),
+                             init="scaled"),
+                "conv_b": ly(Di + 2 * GN, axes=(None,), init="zeros"),
+                "dt_bias": ly(H, axes=(None,), init="zeros"),
+                "a_log": ly(H, axes=(None,), init="zeros"),
+                "d_skip": ly(H, axes=(None,), init="ones"),
+                "norm": ly(Di, axes=(None,), init="ones"),
+                "w_out": ly(Di, D, axes=("mlp", "embed"), init="scaled"),
+            }
+        else:
+            Di = D  # mamba inner width = d_model (hymba parallel heads)
+            N = cfg.ssm_state
+            blk["mamba"] = {
+                "w_in": ly(D, 2 * Di, axes=("embed", "mlp"), init="scaled"),
+                "conv_w": ly(cfg.ssm_conv, Di, axes=(None, "embed"),
+                             init="scaled"),
+                "w_bc": ly(Di, 2 * N + 1, axes=("embed", None), init="scaled"),
+                "a_log": ly(Di, N, axes=("embed", "state"), init="zeros"),
+                "d_skip": ly(Di, axes=("embed",), init="ones"),
+                "w_out": ly(Di, D, axes=("mlp", "embed"), init="scaled"),
+            }
+        if cfg.hybrid_fusion == "norm_mean":
+            blk["norm_attn"] = ly(dh * cfg.n_heads, axes=("heads",),
+                                  init="ones")
+            blk["norm_ssm"] = ly(D, axes=("embed",), init="ones")
     tree["blocks"] = blk
     return tree
 
@@ -179,6 +202,36 @@ def _attn_params(bp: Dict[str, Array], cfg: ArchConfig) -> A.AttnParams:
                         bp["attn"].get("bk"), bp["attn"].get("bv"))
 
 
+def _ssm_zero_state(cfg: ArchConfig, b: int, dtype) -> Tuple[Array, Array]:
+    """A fresh hybrid layer's (conv tail, SSM state) for ``b`` rows."""
+    tail, h = S.ssm_state_shapes(cfg)
+    return (jnp.zeros((b,) + tail, dtype), jnp.zeros((b,) + h, jnp.float32))
+
+
+def _ssm_branch(h: Array, bp: Dict[str, Any], cfg: ArchConfig,
+                pol: ExecutionPolicy, state, **kw):
+    """A hybrid layer's SSM heads: Mamba-2 where ``cfg.ssm_heads`` is
+    set, else hymba's Mamba."""
+    if cfg.ssm_heads:
+        return S.mamba2_mix(h, S.Mamba2Params(**bp["mamba2"]), cfg, pol,
+                            state, **kw)
+    return S.mamba_mix(h, S.MambaParams(**bp["mamba"]), cfg, pol, state,
+                       **kw)
+
+
+def _fuse(x: Array, attn_out: Array, ssm_out: Array, bp: Dict[str, Any],
+          cfg: ArchConfig) -> Array:
+    """Join a hybrid layer's two branches into the residual stream."""
+    if cfg.hybrid_fusion == "sum":         # Falcon-H1
+        return x + (attn_out + ssm_out)
+    if cfg.hybrid_fusion != "norm_mean":
+        raise ValueError(f"unknown hybrid_fusion {cfg.hybrid_fusion!r}")
+    # hymba: normalise each branch, then average
+    attn_out = L.rms_norm(attn_out, bp["norm_attn"], cfg.norm_eps)
+    ssm_out = L.rms_norm(ssm_out, bp["norm_ssm"], cfg.norm_eps)
+    return x + 0.5 * (attn_out + ssm_out)
+
+
 def block_forward(x: Array, bp: Dict[str, Any], cfg: ArchConfig,
                   pol: ExecutionPolicy, positions: Array, window: Array,
                   ) -> Tuple[Array, Array]:
@@ -203,14 +256,9 @@ def block_forward(x: Array, bp: Dict[str, Any], cfg: ArchConfig,
     attn_out = L.dense(ctx.reshape(*x.shape[:2], -1), bp["attn"]["wo"], pol)
 
     if cfg.family == "hybrid":
-        b, t, d = h.shape
-        st = (jnp.zeros((b, cfg.ssm_conv - 1, d), h.dtype),
-              jnp.zeros((b, d, cfg.ssm_state), jnp.float32))
-        ssm_out, _ = S.mamba_mix(h, S.MambaParams(**bp["mamba"]), cfg, pol, st)
-        # hymba fusion: normalise each branch, then average
-        attn_out = L.rms_norm(attn_out, bp["norm_attn"], cfg.norm_eps)
-        ssm_out = L.rms_norm(ssm_out, bp["norm_ssm"], cfg.norm_eps)
-        x = x + 0.5 * (attn_out + ssm_out)
+        st = _ssm_zero_state(cfg, h.shape[0], h.dtype)
+        ssm_out, _ = _ssm_branch(h, bp, cfg, pol, st)
+        x = _fuse(x, attn_out, ssm_out, bp, cfg)
     else:
         x = x + attn_out
     x = constrain(x, ("batch", "seq", "embed"))
@@ -317,13 +365,15 @@ class DecodeState(NamedTuple):
     x_prev: Optional[Array] = None      # (L,B,D) rwkv token-shift boundary
     cm_prev: Optional[Array] = None     # (L,B,D) rwkv channel-mix boundary
     wkv: Optional[Array] = None         # (L,B,H,dk,dk) rwkv state
-    conv_tail: Optional[Array] = None   # (L,B,K-1,Di) mamba conv tail
-    ssm_h: Optional[Array] = None       # (L,B,Di,N) mamba state
+    # hybrid: mamba conv tail (L,B,K-1,C) and state, shaped by
+    # ssm.ssm_state_shapes: hymba (L,B,D,N), Mamba-2 (L,B,H,P,N)
+    conv_tail: Optional[Array] = None
+    ssm_h: Optional[Array] = None
     # per-block int8 cache scales (cache_quant="int8" only)
     scale_k: Optional[Array] = None     # (L,B,S,Hkv,1)
     scale_v: Optional[Array] = None     # (L,B,S,Hkv,1)
     wkv_scale: Optional[Array] = None   # (L,B,H,dk,1)
-    ssm_scale: Optional[Array] = None   # (L,B,Di,1)
+    ssm_scale: Optional[Array] = None   # ssm_h's shape, last axis 1
 
 
 def _cache_quant(cfg: ArchConfig) -> bool:
@@ -372,11 +422,13 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_seq: int,
             fields["wkv_scale"] = mk((Lr, batch, cfg.n_heads, dh, 1),
                                      jnp.float32)
     if cfg.family == "hybrid":
-        fields["conv_tail"] = mk((Lr, batch, cfg.ssm_conv - 1, D), dt)
-        fields["ssm_h"] = mk((Lr, batch, D, cfg.ssm_state),
+        tail, hs = S.ssm_state_shapes(cfg)
+        fields["conv_tail"] = mk((Lr, batch) + tail, dt)
+        fields["ssm_h"] = mk((Lr, batch) + hs,
                              jnp.int8 if qc else jnp.float32)
         if qc:
-            fields["ssm_scale"] = mk((Lr, batch, D, 1), jnp.float32)
+            fields["ssm_scale"] = mk((Lr, batch) + hs[:-1] + (1,),
+                                     jnp.float32)
     return DecodeState(**fields)
 
 
@@ -469,11 +521,9 @@ def decode_step(params: Dict[str, Any], state: DecodeState,
                 hprev = dequantize_blocked(hq_, hs_)
             else:
                 tail, hprev = extra
-            ssm_out, (tail2, h2) = S.mamba_mix(
-                h, S.MambaParams(**bp["mamba"]), cfg, pol, (tail, hprev))
-            attn_out = L.rms_norm(attn_out, bp["norm_attn"], cfg.norm_eps)
-            ssm_out = L.rms_norm(ssm_out, bp["norm_ssm"], cfg.norm_eps)
-            x = x + 0.5 * (attn_out + ssm_out)
+            ssm_out, (tail2, h2) = _ssm_branch(h, bp, cfg, pol,
+                                               (tail, hprev))
+            x = _fuse(x, attn_out, ssm_out, bp, cfg)
             if qc:
                 h2, h2_s = quantize_blocked(h2)
                 new_extra = (tail2, h2, h2_s)
@@ -599,14 +649,10 @@ def prefill(params, batch, cfg: ArchConfig,
         attn_out = L.dense(ctx.reshape(b, s, -1), bp["attn"]["wo"], pol)
         ys_extra = ()
         if cfg.family == "hybrid":
-            st = (jnp.zeros((b, cfg.ssm_conv - 1, cfg.d_model), h.dtype),
-                  jnp.zeros((b, cfg.d_model, cfg.ssm_state), jnp.float32))
-            ssm_out, (tail, hh) = S.mamba_mix(
-                h, S.MambaParams(**bp["mamba"]), cfg, pol, st,
+            ssm_out, (tail, hh) = _ssm_branch(
+                h, bp, cfg, pol, _ssm_zero_state(cfg, b, h.dtype),
                 mask=mask, lengths=lengths)
-            attn_out = L.rms_norm(attn_out, bp["norm_attn"], cfg.norm_eps)
-            ssm_out = L.rms_norm(ssm_out, bp["norm_ssm"], cfg.norm_eps)
-            x = x + 0.5 * (attn_out + ssm_out)
+            x = _fuse(x, attn_out, ssm_out, bp, cfg)
             ys_extra = (tail, hh)
         else:
             x = x + attn_out
@@ -940,12 +986,9 @@ def verify_step(params: Dict[str, Any], state: DecodeState,
                 hprev = dequantize_blocked(hq_, hs_)
             else:
                 tail, hprev = extra
-            ssm_out, (tail2, h2), (tail_steps, h_steps) = S.mamba_mix(
-                h, S.MambaParams(**bp["mamba"]), cfg, pol, (tail, hprev),
-                return_states=True)
-            attn_out = L.rms_norm(attn_out, bp["norm_attn"], cfg.norm_eps)
-            ssm_out = L.rms_norm(ssm_out, bp["norm_ssm"], cfg.norm_eps)
-            x = x + 0.5 * (attn_out + ssm_out)
+            ssm_out, (tail2, h2), (tail_steps, h_steps) = _ssm_branch(
+                h, bp, cfg, pol, (tail, hprev), return_states=True)
+            x = _fuse(x, attn_out, ssm_out, bp, cfg)
             if qc:
                 h2, h2_s = quantize_blocked(h2)
                 new_extra = (tail2, h2, h2_s, tail_steps, h_steps)

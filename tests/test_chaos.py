@@ -95,6 +95,9 @@ CHAOS_MATRIX = [
     ("hymba-1.5b", None, 0, 4),
     ("hymba-1.5b", CacheSpec(dtype="int8", paged=True, page_size=PAGE),
      0, 3),
+    ("falcon-h1-34b", None, 0, 4),
+    ("falcon-h1-34b", CacheSpec(dtype="int8", paged=True, page_size=PAGE),
+     0, 3),
 ]
 
 

@@ -1,6 +1,6 @@
 """Per-architecture smoke tests (reduced configs) + model-level invariants.
 
-Each of the 10 assigned architectures instantiates a reduced config of the
+Each of the 11 registered architectures instantiates a reduced config of the
 same family and runs one forward/train step on CPU, asserting output shapes
 and the absence of NaNs — plus serving-path consistency checks.
 """
@@ -66,7 +66,8 @@ def test_smoke_serving_consistency(arch):
     assert bool(jnp.isfinite(dl.astype(jnp.float32)).all())
 
 
-@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b"])
+@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b",
+                                  "falcon-h1-34b"])
 def test_decode_matches_forward(arch):
     """Teacher-forced decode of the last token == forward at that position."""
     cfg = get_arch(arch).reduced().scaled(dtype="float32")
@@ -203,3 +204,131 @@ def test_fused_moe_ffn_matches_unfused():
     a = np.asarray(m.forward(params, batch))
     b = np.asarray(mf.forward(params, batch))
     np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# Falcon-H1's Mamba-2 mixer: the chunked SSD scan against the recurrence
+# ---------------------------------------------------------------------------
+
+def _mamba2_setup(seed=0):
+    """A reduced falcon-h1-34b in float32 (two groups of two heads, chunks
+    of 8), one layer's mixer weights with a non-trivial decay and dt."""
+    from repro.models import ssm as S
+    cfg = get_arch("falcon-h1-34b").reduced().scaled(dtype="float32")
+    params = build_model(cfg).init(jax.random.PRNGKey(seed))
+    bp = jax.tree.map(lambda a: a[0], params["blocks"]["mamba2"])
+    h = cfg.ssm_heads
+    bp["a_log"] = jax.random.uniform(jax.random.PRNGKey(5), (h,),
+                                     minval=0.0, maxval=2.0)
+    bp["dt_bias"] = jax.random.uniform(jax.random.PRNGKey(6), (h,),
+                                       minval=-4.0, maxval=0.0)
+    bp["conv_b"] = 0.1 * jax.random.normal(jax.random.PRNGKey(7),
+                                           bp["conv_b"].shape)
+    return cfg, S.Mamba2Params(**bp)
+
+
+@pytest.mark.parametrize("t,lengths", [
+    (13, None),                 # ends mid-chunk
+    (8, None),                  # exactly one chunk
+    (29, None),                 # several chunks, the last cut short
+    (24, (24, 9, 17)),          # right padding: rows end in other chunks
+], ids=["mid-chunk", "one-chunk", "several-chunks", "right-padded"])
+def test_ssd_chunked_scan_equals_stepwise_recurrence(t, lengths):
+    """The prefill's chunked SSD form gives the outputs, the final state
+    and the conv tail of the step-by-step recurrence (the path decode and
+    verify run), from a non-zero starting state; pad steps leave the
+    state exactly where the row's last real token put it."""
+    from repro.models import ssm as S
+    cfg, p = _mamba2_setup()
+    b = 3 if lengths else 2
+    x = jax.random.normal(jax.random.PRNGKey(3), (b, t, cfg.d_model))
+    tail, hs = S.ssm_state_shapes(cfg)
+    st = (jax.random.normal(jax.random.PRNGKey(8), (b,) + tail),
+          jax.random.normal(jax.random.PRNGKey(4), (b,) + hs))
+    kw = {}
+    if lengths:
+        n = jnp.asarray(lengths, jnp.int32)
+        kw = dict(mask=jnp.arange(t)[None, :] < n[:, None], lengths=n)
+    out, (tail_c, s_c) = S.mamba2_mix(x, p, cfg, cfg.exec_policy, st, **kw)
+    ref, (tail_r, s_r), (tails, steps) = S.mamba2_mix(
+        x, p, cfg, cfg.exec_policy, st, return_states=True, **kw)
+    scale = float(jnp.abs(s_r).max())
+    np.testing.assert_allclose(np.asarray(s_c), np.asarray(s_r),
+                               atol=2e-6 * scale)
+    np.testing.assert_array_equal(np.asarray(tail_c), np.asarray(tail_r))
+    for i in range(b):
+        n_i = lengths[i] if lengths else t
+        np.testing.assert_allclose(np.asarray(out[i, :n_i]),
+                                   np.asarray(ref[i, :n_i]), atol=2e-5)
+        # the last real step's checkpoint is the carried state
+        np.testing.assert_allclose(np.asarray(steps[i, n_i - 1]),
+                                   np.asarray(s_r[i]), atol=0)
+
+
+@pytest.mark.parametrize("cut", [1, 7, 8, 13, 20])
+def test_falcon_prefill_then_decode_equals_forward(cut):
+    """A prompt prefilled to ``cut`` tokens (mid-chunk, on a chunk
+    boundary, or one token), then decoded one token at a time through the
+    KV cache and the Mamba-2 state, gives at every position the logits of
+    one full forward pass."""
+    cfg = get_arch("falcon-h1-34b").reduced().scaled(dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    full = model.make_batch(jax.random.PRNGKey(1), 2, 24, "prefill")
+    want = model.forward(params, full)
+    lg, st = model.prefill(params, {"tokens": full["tokens"][:, :cut]},
+                           headroom=24)
+    got = [lg[:, 0]]
+    for j in range(cut, 24):
+        lg, st = model.decode_step(params, st,
+                                   {"tokens": full["tokens"][:, j:j + 1]})
+        got.append(lg[:, 0])
+    got = jnp.stack(got[:-1], axis=1)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(want[:, cut - 1:23]), atol=2e-5)
+
+
+def test_hybrid_fusion_follows_the_config():
+    """hymba keeps its per-branch norms; Falcon-H1's summed branches have
+    none, and its SSM state is shaped by its Mamba-2 heads."""
+    hy = build_model(get_arch("hymba-1.5b").reduced())
+    fa = build_model(get_arch("falcon-h1-34b").reduced())
+    assert {"norm_attn", "norm_ssm", "mamba"} <= set(
+        hy.abstract_params()["blocks"])
+    blk = fa.abstract_params()["blocks"]
+    assert "mamba2" in blk
+    assert not {"norm_attn", "norm_ssm", "mamba"} & set(blk)
+    cfg = fa.cfg
+    st = T.init_decode_state(cfg, 3, 32, abstract=True)
+    assert st.ssm_h.shape == (cfg.n_layers, 3, cfg.ssm_heads,
+                              cfg.ssm_head_dim, cfg.ssm_state)
+    assert st.conv_tail.shape == (
+        cfg.n_layers, 3, cfg.ssm_conv - 1,
+        cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state)
+
+
+def test_mamba2_step_checkpoints_are_one_loop_not_unrolled():
+    """The per-step checkpoints (verify and the paged extend) come from one
+    ``lax.scan`` over the steps: the traced program has as many equations
+    at 64 steps as at 8, and each step's conv tail is the K-1 inputs
+    before it."""
+    from repro.models import ssm as S
+    cfg, p = _mamba2_setup()
+    tail, hs = S.ssm_state_shapes(cfg)
+
+    def n_eqns(t):
+        def f(x):
+            st = (jnp.zeros((1,) + tail), jnp.zeros((1,) + hs))
+            return S.mamba2_mix(x, p, cfg, cfg.exec_policy, st,
+                                return_states=True)
+        return len(jax.make_jaxpr(f)(jnp.zeros((1, t, cfg.d_model))).eqns)
+
+    assert n_eqns(64) == n_eqns(8)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 11, cfg.d_model))
+    st = (jax.random.normal(jax.random.PRNGKey(8), (2,) + tail),
+          jnp.zeros((2,) + hs))
+    _, _, (tails, _) = S.mamba2_mix(x, p, cfg, cfg.exec_policy, st,
+                                    return_states=True)
+    _, (tail_6, _) = S.mamba2_mix(x[:, :6], p, cfg, cfg.exec_policy, st)
+    np.testing.assert_array_equal(np.asarray(tails[:, 5]),
+                                  np.asarray(tail_6))

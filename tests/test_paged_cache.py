@@ -36,7 +36,7 @@ from repro.runtime.serve_loop import Request, ServeConfig, ServeEngine
 
 MAX_SEQ = 64
 PAGE = 8
-FAMILIES = ["glm4-9b", "rwkv6-3b", "hymba-1.5b"]
+FAMILIES = ["glm4-9b", "rwkv6-3b", "hymba-1.5b", "falcon-h1-34b"]
 
 
 @pytest.fixture(scope="module")

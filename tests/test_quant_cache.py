@@ -32,7 +32,7 @@ from repro.runtime.serve_loop import Request, ServeEngine
 
 _BASELINE = os.path.join(os.path.dirname(__file__), os.pardir,
                          "benchmarks", "quant_baseline.json")
-ARCHS = ("glm4-9b", "rwkv6-3b", "hymba-1.5b")
+ARCHS = ("glm4-9b", "rwkv6-3b", "hymba-1.5b", "falcon-h1-34b")
 
 
 # ---------------------------------------------------------------- numerics

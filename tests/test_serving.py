@@ -65,7 +65,8 @@ def _single_stream(model, params, prompt, max_new):
     return seq
 
 
-@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b"])
+@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b",
+                                  "falcon-h1-34b"])
 def test_output_equality_with_single_stream(served, arch):
     """Continuous batching must not change a single request's tokens —
     across attention (KV cache), rwkv (recurrent) and hybrid state."""

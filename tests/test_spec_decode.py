@@ -78,7 +78,8 @@ def _mixed_requests(cfg, lens, max_news, seed=0):
 # Greedy bit-exactness across every stateful family
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b"])
+@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b",
+                                  "falcon-h1-34b"])
 def test_greedy_bitexact_vs_plain_decode(served, arch):
     """Spec decode must not change a single token — attention KV, rwkv
     recurrent and hybrid conv/ssm state all roll back exactly."""
@@ -103,7 +104,8 @@ def test_greedy_bitexact_vs_plain_decode(served, arch):
 # Rollback correctness after rejection (model-layer contract)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b"])
+@pytest.mark.parametrize("arch", ["glm4-9b", "rwkv6-3b", "hymba-1.5b",
+                                  "falcon-h1-34b"])
 def test_rollback_after_rejection(served, arch):
     """verify_step + spec_commit with a partial advance must reproduce the
     plain-decode state exactly: logits, pos, recurrent fields — and the

@@ -202,3 +202,38 @@ def test_rwkv6_prefill_runs_no_loop_a_token(one_chip):
     ).lower(params, tokens, lengths).compile().as_text()
     sizes = _loop_body_sizes(hlo)
     assert sizes and min(sizes) >= 64, sizes
+
+
+def test_falcon_h1_serves_at_published_widths_on_one_chip(one_chip):
+    """falcon-h1-34b at published widths (6 of its 72 layers, a pipeline
+    stage and the embedding and head) over 16 slots of 8704 positions:
+    the decode step fits one chip's HBM with its KV cache and float32
+    Mamba-2 state, and a one-row prefill (a 512 bucket, where attention
+    has no loop) compiles to two device loops, the layers and the SSD
+    scan's 128-token chunks, and none a token."""
+    cfg = get_arch("falcon-h1-34b").scaled(n_layers=6)
+    model = build_model(cfg)
+
+    def place(t):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), t)
+
+    params = place(model.abstract_params())
+    state = place(model.init_slot_state(16, 8704, abstract=True))
+    tokens = _sds((16, 1), jnp.int32, one_chip)
+    compiled = jax.jit(
+        lambda p, s, t: model.decode_step(p, s, {"tokens": t}),
+        donate_argnums=(1,)).lower(params, state, tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes > 12e9    # weights, cache and state
+    assert total < V5E_HBM_BYTES, total
+
+    tokens = _sds((1, 512), jnp.int32, one_chip)
+    lengths = _sds((1,), jnp.int32, one_chip)
+    hlo = jax.jit(
+        lambda p, t, n: model.prefill(p, {"tokens": t}, headroom=0,
+                                      lengths=n)
+    ).lower(params, tokens, lengths).compile().as_text()
+    sizes = _loop_body_sizes(hlo)
+    assert len(sizes) == 2, sizes
