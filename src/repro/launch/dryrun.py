@@ -48,11 +48,14 @@ def batch_shardings(mesh, specs, batch_axes=("pod", "data")):
 
 
 _STATE_AXES = {
-    # cache sequence dim shards over model ("seq" rule): none of the
-    # assigned archs can shard kv heads over tp=16, and a replicated 32k
-    # cache is the decode memory bottleneck (see EXPERIMENTS.md #Perf).
-    "cache_k": ("layers", "batch", "seq", "kv_heads", None),
-    "cache_v": ("layers", "batch", "seq", "kv_heads", None),
+    # the head-major cache resolves kv heads before seq: kv heads shard
+    # over model where their count divides it (each device attends over
+    # whole heads), else the cache sequence dim does ("seq" rule).  None
+    # of the assigned archs can shard kv heads over tp=16, so they shard
+    # seq: a replicated 32k cache is the decode memory bottleneck (see
+    # EXPERIMENTS.md #Perf).
+    "cache_k": ("layers", "batch", "kv_heads", "seq", None),
+    "cache_v": ("layers", "batch", "kv_heads", "seq", None),
     "pos": (),
     "x_prev": ("layers", "batch", None),
     "cm_prev": ("layers", "batch", None),

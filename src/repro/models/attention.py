@@ -266,30 +266,55 @@ def _attend_verify(q: Array, keys: Array, vals: Array, posv: Array,
     return ctx.reshape(b, kq, hq, dh)
 
 
+# Dense cache layout: head-major, (B, Hkv, S, dh) a layer, (L, B, Hkv, S,
+# dh) stacked, and (..., 1) for the int8 scales.  Each (batch, head) is
+# then one (S, dh) matrix, the layout the score and value products read
+# on a TPU, so decode reads a layer's slice of the stack in place; the
+# attend halves see the logical (B, S, Hkv, dh) view (`_token_major`).
+
+def _token_major(cache: Array) -> Array:
+    """The logical (B, S, Hkv, dh) view of a head-major layer cache."""
+    return jnp.swapaxes(cache, 1, 2)
+
+
+def kv_index(rows: Array, cols: Array, n_heads: int):
+    """Index of every head's entry at (row, column) of a head-major
+    cache: ``rows`` and ``cols`` broadcast to the leading axes of the
+    (..., Hkv, dh) values written or read there."""
+    heads = jnp.arange(n_heads)
+    return rows[..., None], heads, cols[..., None]
+
+
 @jax.named_scope("attention")
 def decode_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
                      cache_v: Array, pos: Array, cfg: ArchConfig,
                      pol: ExecutionPolicy, window,
                      scale_k: Optional[Array] = None,
-                     scale_v: Optional[Array] = None):
-    """q/k_new/v_new: (B,1,H*,dh); cache: (B,S,Hkv,dh) ring-written at pos.
+                     scale_v: Optional[Array] = None, *, layer):
+    """q/k_new/v_new: (B,1,H*,dh); cache: (L,B,Hkv,S,dh), every layer's,
+    ring-written at pos in layer ``layer``.
+
+    The new column is written into the stacked cache in place, and the
+    layer's slice is read back from it for the attend: the decode layer
+    loop carries the whole stack, so no layer's cache is copied out and
+    back.
 
     ``pos`` is the tokens-seen counter: a scalar (every row at the same
     position — the classic single-stream path) or a ``(B,)`` vector (the
     serving engine's per-slot positions, where each decode slot was
     prefilled at a different time and length).
 
-    With ``scale_k``/``scale_v`` (B,S,Hkv,nb) the cache is the per-block
+    With ``scale_k``/``scale_v`` (L,B,Hkv,S,nb) the cache is the per-block
     int8 format of :mod:`repro.core.quant_cache`: each new K/V vector is
     quantized on write (its scale lands at the same ring slot) and the
-    whole cache is dequantized on read.  Without them, an int8 cache is
+    layer's cache is dequantized on read.  Without them, an int8 cache is
     the legacy fixed-scale Q3.4 format (:data:`KV_Q_SCALE`).
 
     Returns (ctx (B,1,Hq,dh), cache_k, cache_v) — plus the updated
     (scale_k, scale_v) when per-block scales are in play.
     """
     b, _, hq, dh = q.shape
-    s_max = cache_k.shape[1]
+    s_max = cache_k.shape[3]
     slot = jnp.mod(pos, s_max)
     blocked = scale_k is not None
     if blocked:
@@ -299,30 +324,32 @@ def decode_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
         quant = cache_k.dtype == jnp.int8
         k_w = quantize_kv(k_new) if quant else k_new.astype(cache_k.dtype)
         v_w = quantize_kv(v_new) if quant else v_new.astype(cache_v.dtype)
-    per_row = jnp.ndim(pos) == 1
-    if per_row:
+    if jnp.ndim(pos) == 1:
         # batched scatter: each row's new K/V lands at its own column
         # (a one-column write, not a full-cache select)
-        rows = jnp.arange(b)
-        cache_k = cache_k.at[rows, slot].set(k_w[:, 0])
-        cache_v = cache_v.at[rows, slot].set(v_w[:, 0])
-        if blocked:
-            scale_k = scale_k.at[rows, slot].set(k_s[:, 0])
-            scale_v = scale_v.at[rows, slot].set(v_s[:, 0])
+        idx = (layer,) + kv_index(jnp.arange(b), slot, cache_k.shape[2])
+
+        def put(cache, new):
+            return cache.at[idx].set(new[:, 0])
     else:
-        cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k_w, slot,
-                                                      axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v_w, slot,
-                                                      axis=1)
-        if blocked:
-            scale_k = jax.lax.dynamic_update_slice_in_dim(scale_k, k_s,
-                                                          slot, axis=1)
-            scale_v = jax.lax.dynamic_update_slice_in_dim(scale_v, v_s,
-                                                          slot, axis=1)
-    keys = (dequantize_blocked(cache_k, scale_k, q.dtype) if blocked
-            else dequantize_kv(cache_k, q.dtype))
-    vals = (dequantize_blocked(cache_v, scale_v, q.dtype) if blocked
-            else dequantize_kv(cache_v, q.dtype))
+        def put(cache, new):
+            return jax.lax.dynamic_update_slice(
+                cache, _token_major(new)[None], (layer, 0, 0, slot, 0))
+
+    def view(cache):
+        return _token_major(
+            jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False))
+
+    cache_k = put(cache_k, k_w)
+    cache_v = put(cache_v, v_w)
+    if blocked:
+        scale_k = put(scale_k, k_s)
+        scale_v = put(scale_v, v_s)
+        keys = dequantize_blocked(view(cache_k), view(scale_k), q.dtype)
+        vals = dequantize_blocked(view(cache_v), view(scale_v), q.dtype)
+    else:
+        keys = dequantize_kv(view(cache_k), q.dtype)
+        vals = dequantize_kv(view(cache_v), q.dtype)
     ctx = _attend_decode(q, keys, vals, pos, pol, window)
     if blocked:
         return ctx, cache_k, cache_v, scale_k, scale_v
@@ -338,7 +365,8 @@ def verify_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
                      ring: bool = False):
     """Speculative verify: K candidate positions scored in one pass.
 
-    q/k_new/v_new: (B,K,H*,dh) — row b's candidates sit at absolute
+    q/k_new/v_new: (B,K,H*,dh); cache: a layer's (B,Hkv,S,dh) (scales
+    (B,Hkv,S,nb)) — row b's candidates sit at absolute
     positions ``pos[b] .. pos[b]+K-1``.  All K K/V columns are written
     first, then every query is masked to its own committed history plus
     the *earlier* candidates of this call:
@@ -374,7 +402,7 @@ def verify_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
     evicted-column tuple as the last element in ring mode.
     """
     b, kq, hq, dh = q.shape
-    s_max = cache_k.shape[1]
+    s_max = cache_k.shape[2]
     posv = pos if jnp.ndim(pos) == 1 else jnp.broadcast_to(pos, (b,))
     offs = jnp.arange(kq, dtype=posv.dtype)
     wpos = posv[:, None] + offs[None, :]                  # (B,K) absolute
@@ -387,6 +415,12 @@ def verify_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
         k_w = quantize_kv(k_new) if quant else k_new.astype(cache_k.dtype)
         v_w = quantize_kv(v_new) if quant else v_new.astype(cache_v.dtype)
     rows = jnp.arange(b)[:, None]
+    hkv = cache_k.shape[1]
+
+    def view(cache, scale):
+        return _token_major(dequantize_blocked(cache, scale, q.dtype)
+                            if blocked else dequantize_kv(cache, q.dtype))
+
     old_keys = old_vals = None
     evicted = ()
     if ring:
@@ -396,30 +430,27 @@ def verify_attention(q: Array, k_new: Array, v_new: Array, cache_k: Array,
         # :func:`~repro.models.transformer.spec_commit` can restore the
         # ones whose candidate the host rejects (a rejected wrapped
         # write would otherwise shadow live history)
-        old_keys = (dequantize_blocked(cache_k, scale_k, q.dtype) if blocked
-                    else dequantize_kv(cache_k, q.dtype))
-        old_vals = (dequantize_blocked(cache_v, scale_v, q.dtype) if blocked
-                    else dequantize_kv(cache_v, q.dtype))
-        slots = jnp.mod(wpos, s_max)
-        evicted = (cache_k[rows, slots], cache_v[rows, slots])
+        old_keys = view(cache_k, scale_k)
+        old_vals = view(cache_v, scale_v)
+        idx = kv_index(rows, jnp.mod(wpos, s_max), hkv)
+        evicted = (cache_k[idx], cache_v[idx])
         if blocked:
-            evicted += (scale_k[rows, slots], scale_v[rows, slots])
-        cache_k = cache_k.at[rows, slots].set(k_w)
-        cache_v = cache_v.at[rows, slots].set(v_w)
+            evicted += (scale_k[idx], scale_v[idx])
+        cache_k = cache_k.at[idx].set(k_w)
+        cache_v = cache_v.at[idx].set(v_w)
         if blocked:
-            scale_k = scale_k.at[rows, slots].set(k_s)
-            scale_v = scale_v.at[rows, slots].set(v_s)
+            scale_k = scale_k.at[idx].set(k_s)
+            scale_v = scale_v.at[idx].set(v_s)
     else:
         # linear-cache write: out-of-range columns drop (never wrap)
-        cache_k = cache_k.at[rows, wpos].set(k_w, mode="drop")
-        cache_v = cache_v.at[rows, wpos].set(v_w, mode="drop")
+        idx = kv_index(rows, wpos, hkv)
+        cache_k = cache_k.at[idx].set(k_w, mode="drop")
+        cache_v = cache_v.at[idx].set(v_w, mode="drop")
         if blocked:
-            scale_k = scale_k.at[rows, wpos].set(k_s, mode="drop")
-            scale_v = scale_v.at[rows, wpos].set(v_s, mode="drop")
-    keys = (dequantize_blocked(cache_k, scale_k, q.dtype) if blocked
-            else dequantize_kv(cache_k, q.dtype))
-    vals = (dequantize_blocked(cache_v, scale_v, q.dtype) if blocked
-            else dequantize_kv(cache_v, q.dtype))
+            scale_k = scale_k.at[idx].set(k_s, mode="drop")
+            scale_v = scale_v.at[idx].set(v_s, mode="drop")
+    keys = view(cache_k, scale_k)
+    vals = view(cache_v, scale_v)
     ctx = _attend_verify(q, keys, vals, posv, pol, window,
                          old_keys=old_keys, old_vals=old_vals)
     if ring:
@@ -448,11 +479,16 @@ def paged_gather(pool: Array, table: Array) -> Array:
     return g.reshape((b, p * pool.shape[1]) + pool.shape[2:])
 
 
-def _paged_write(pool: Array, idx: Array, new: Array) -> Array:
-    """Scatter rows into a pool through flat token indices (drop OOB)."""
-    n, page = pool.shape[:2]
-    flat = pool.reshape((n * page,) + pool.shape[2:])
-    flat = flat.at[idx].set(new, mode="drop")
+def _paged_write(pool: Array, idx: Array, new: Array, layer=None
+                 ) -> Array:
+    """Scatter rows into a pool through flat token indices (drop OOB);
+    with ``layer``, into that layer's pool of a stacked (L, N, page, ...)
+    one."""
+    lead = () if layer is None else (layer,)
+    k = len(lead)
+    n, page = pool.shape[k:k + 2]
+    flat = pool.reshape(pool.shape[:k] + (n * page,) + pool.shape[k + 2:])
+    flat = flat.at[lead + (idx,)].set(new, mode="drop")
     return flat.reshape(pool.shape)
 
 
@@ -462,18 +498,20 @@ def paged_decode_attention(q: Array, k_new: Array, v_new: Array,
                            pos: Array, cfg: ArchConfig,
                            pol: ExecutionPolicy, window,
                            scale_k: Optional[Array] = None,
-                           scale_v: Optional[Array] = None):
-    """:func:`decode_attention` over a pooled cache (see module note).
+                           scale_v: Optional[Array] = None, *, layer):
+    """:func:`decode_attention` over a pooled cache (see module note),
+    every layer's pool stacked (L, N, page, ...) as there.
 
-    The new K/V vector lands at flat pool index ``table[b, pos//page] *
-    page + pos%page`` (drop through the sentinel / past logical
-    capacity — the paged cache is linear, never ring-wrapped), then the
-    pool is gathered back to the logical (B, S, Hkv, dh) view and the
-    shared :func:`_attend_decode` half runs unchanged — which is what
-    keeps paged decode bit-identical to the dense layout.
+    The new K/V vector lands in layer ``layer`` at flat pool index
+    ``table[b, pos//page] * page + pos%page`` (drop through the sentinel
+    / past logical capacity — the paged cache is linear, never
+    ring-wrapped), then the layer's pool is gathered back to the logical
+    (B, S, Hkv, dh) view and the shared :func:`_attend_decode` half runs
+    unchanged — which is what keeps paged decode bit-identical to the
+    dense layout.
     """
     b = q.shape[0]
-    n, page = pool_k.shape[:2]
+    n, page = pool_k.shape[1:3]
     s_log = table.shape[1] * page
     posv = pos if jnp.ndim(pos) == 1 else jnp.broadcast_to(pos, (b,))
     blocked = scale_k is not None
@@ -487,18 +525,25 @@ def paged_decode_attention(q: Array, k_new: Array, v_new: Array,
     blk = table[rows, jnp.minimum(posv // page, table.shape[1] - 1)]
     idx = blk * page + jnp.mod(posv, page)
     idx = jnp.where(posv < s_log, idx, n * page)          # linear: drop OOB
-    pool_k = _paged_write(pool_k, idx, k_w[:, 0])
-    pool_v = _paged_write(pool_v, idx, v_w[:, 0])
+
+    def put(pool, new):
+        return _paged_write(pool, idx, new[:, 0], layer)
+
+    def view(pool):
+        return paged_gather(
+            jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False),
+            table)
+
+    pool_k = put(pool_k, k_w)
+    pool_v = put(pool_v, v_w)
     if blocked:
-        scale_k = _paged_write(scale_k, idx, k_s[:, 0])
-        scale_v = _paged_write(scale_v, idx, v_s[:, 0])
-        keys = dequantize_blocked(paged_gather(pool_k, table),
-                                  paged_gather(scale_k, table), q.dtype)
-        vals = dequantize_blocked(paged_gather(pool_v, table),
-                                  paged_gather(scale_v, table), q.dtype)
+        scale_k = put(scale_k, k_s)
+        scale_v = put(scale_v, v_s)
+        keys = dequantize_blocked(view(pool_k), view(scale_k), q.dtype)
+        vals = dequantize_blocked(view(pool_v), view(scale_v), q.dtype)
     else:
-        keys = dequantize_kv(paged_gather(pool_k, table), q.dtype)
-        vals = dequantize_kv(paged_gather(pool_v, table), q.dtype)
+        keys = dequantize_kv(view(pool_k), q.dtype)
+        vals = dequantize_kv(view(pool_v), q.dtype)
     ctx = _attend_decode(q, keys, vals, posv, pol, window)
     if blocked:
         return ctx, pool_k, pool_v, scale_k, scale_v

@@ -354,11 +354,17 @@ def loss_fn(params, batch, cfg: ArchConfig,
 class DecodeState(NamedTuple):
     """Stacked (n_layers leading dim) recurrent state for every family.
 
+    :func:`decode_step` carries the stacked fields through its layer
+    loop, and layer l writes only what it changes: one K/V column a row
+    and its whole recurrent state.  The K/V
+    cache is head-major, so a layer's (row, head) history is one
+    (S, dh) matrix, the operand the attention products read in place.
+
     The ``*scale*`` fields carry the per-block float32 scales of the
     quantized cache mode (``cfg.cache_quant == "int8"``, see
     :mod:`repro.core.quant_cache`); they stay ``None`` otherwise.
     """
-    cache_k: Optional[Array] = None     # (L,B,S,Hkv,dh)
+    cache_k: Optional[Array] = None     # (L,B,Hkv,S,dh), head-major
     cache_v: Optional[Array] = None
     pos: Optional[Array] = None         # scalar int32 tokens-seen
     # ssm / hybrid
@@ -370,10 +376,35 @@ class DecodeState(NamedTuple):
     conv_tail: Optional[Array] = None
     ssm_h: Optional[Array] = None
     # per-block int8 cache scales (cache_quant="int8" only)
-    scale_k: Optional[Array] = None     # (L,B,S,Hkv,1)
-    scale_v: Optional[Array] = None     # (L,B,S,Hkv,1)
+    scale_k: Optional[Array] = None     # (L,B,Hkv,S,1)
+    scale_v: Optional[Array] = None     # (L,B,Hkv,S,1)
     wkv_scale: Optional[Array] = None   # (L,B,H,dk,1)
     ssm_scale: Optional[Array] = None   # ssm_h's shape, last axis 1
+
+
+def kv_pos_axis(state) -> int:
+    """The position axis of a slot state's stacked K/V leaves: 3 in the
+    dense head-major (L, B, Hkv, S, dh) cache, 2 (within a page) in a
+    paged (L, N, page, Hkv, dh) pool."""
+    return 2 if getattr(state, "block_tables", None) is not None else 3
+
+
+def slot_kv_history(leaf, row: int, n: int):
+    """Row ``row``'s first ``n`` positions of a dense stacked K/V leaf
+    (L, B, Hkv, S, ...), token-major: (L, n, Hkv, ...)."""
+    return leaf[:, row, :, :n].swapaxes(1, 2)
+
+
+def slot_kv_leaf(like, length: int, hist=None) -> np.ndarray:
+    """A one-row host K/V leaf in the dense layout of ``like`` (L, B,
+    Hkv, S, ...), with B = 1 and S = ``length``: token-major ``hist``
+    (L, n, Hkv, ...), as :func:`slot_kv_history` gives it, in its first
+    n positions and zeros after."""
+    out = np.zeros(tuple(like.shape[:1]) + (1, like.shape[2], length)
+                   + tuple(like.shape[4:]), like.dtype)
+    if hist is not None:
+        out[:, 0, :, :hist.shape[1]] = hist.swapaxes(1, 2)
+    return out
 
 
 def _cache_quant(cfg: ArchConfig) -> bool:
@@ -402,14 +433,14 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_seq: int,
         if cfg.sliding_window and cfg.supports_long_context and \
                 max_seq > 65536:
             cache_len = cfg.sliding_window  # long_500k: ring cache only
-        fields["cache_k"] = mk((Lr, batch, cache_len, cfg.n_kv_heads, dh),
+        fields["cache_k"] = mk((Lr, batch, cfg.n_kv_heads, cache_len, dh),
                                kv_dt)
-        fields["cache_v"] = mk((Lr, batch, cache_len, cfg.n_kv_heads, dh),
+        fields["cache_v"] = mk((Lr, batch, cfg.n_kv_heads, cache_len, dh),
                                kv_dt)
         if qc:
-            fields["scale_k"] = mk((Lr, batch, cache_len, cfg.n_kv_heads, 1),
+            fields["scale_k"] = mk((Lr, batch, cfg.n_kv_heads, cache_len, 1),
                                    jnp.float32)
-            fields["scale_v"] = mk((Lr, batch, cache_len, cfg.n_kv_heads, 1),
+            fields["scale_v"] = mk((Lr, batch, cfg.n_kv_heads, cache_len, 1),
                                    jnp.float32)
     if cfg.family == "ssm":
         fields["x_prev"] = mk((Lr, batch, D), dt)
@@ -432,12 +463,83 @@ def init_decode_state(cfg: ArchConfig, batch: int, max_seq: int,
     return DecodeState(**fields)
 
 
+# DecodeState fields stacked over layers that the decode layer loop
+# carries whole; layer l reads its slice and writes back only what it
+# changes (one K/V column a row, its whole recurrent state)
+_KV_FIELDS = ("cache_k", "cache_v", "scale_k", "scale_v")
+_LAYER_FIELDS = _KV_FIELDS + ("conv_tail", "ssm_h", "ssm_scale", "x_prev",
+                              "cm_prev", "wkv", "wkv_scale")
+# the int8 recurrent fields and the per-block scale field of each
+_SCALE_FOR = {"wkv": "wkv_scale", "ssm_h": "ssm_scale"}
+# the head-major cache's logical axes (spec_for resolves kv heads first)
+_CACHE_AXES = ("layers", "batch", "kv_heads", "seq", None)
+
+
+def _read_layer(st: Dict[str, Array], name: str, layer) -> Array:
+    """Layer ``layer``'s slice of carried field ``name``, dequantized
+    where the field is held as int8 beside per-block scales."""
+    v = jax.lax.dynamic_index_in_dim(st[name], layer, 0, keepdims=False)
+    scale = _SCALE_FOR.get(name)
+    if scale in st:
+        v = dequantize_blocked(v, jax.lax.dynamic_index_in_dim(
+            st[scale], layer, 0, keepdims=False))
+    return v
+
+
+def _write_layer(st: Dict[str, Array], layer, **new: Array
+                 ) -> Dict[str, Array]:
+    """``st`` with each ``new`` value written in place as layer
+    ``layer``'s slice of its field (quantized first where int8)."""
+    out = dict(st)
+    for name, v in new.items():
+        scale = _SCALE_FOR.get(name)
+        if scale in st:
+            v, s = quantize_blocked(v)
+            out[scale] = jax.lax.dynamic_update_index_in_dim(st[scale], s,
+                                                             layer, 0)
+        out[name] = jax.lax.dynamic_update_index_in_dim(st[name], v, layer,
+                                                        0)
+    return out
+
+
+def _scan_layers(body, x: Array, params: Dict[str, Any], windows: Array,
+                 state, paged: bool):
+    """Run ``body(x, bp, window, layer, st) -> (x, st)`` over the layers,
+    with the state's stacked fields (:data:`_LAYER_FIELDS`) in the scan's
+    carry as ``st``.  Only the weights and windows are scanned over, so
+    no field is stacked anew as the scan's output and none is copied back
+    after the loop: with the state donated, XLA updates it in place.
+    Returns (x, state with the carried fields replaced)."""
+    def keep_layout(st):
+        if paged:
+            return st
+        return {n: constrain(v, _CACHE_AXES) if n in _KV_FIELDS else v
+                for n, v in st.items()}
+
+    def step(carry, xs):
+        x, layer, st = carry
+        x, st = body(x, *xs, layer, st)
+        return (x, layer + 1, keep_layout(st)), None
+
+    st = {n: getattr(state, n) for n in _LAYER_FIELDS
+          if getattr(state, n) is not None}
+    (x, _, st), _ = jax.lax.scan(
+        step, (x, jnp.int32(0), keep_layout(st)), (params["blocks"], windows))
+    return x, state._replace(**st)
+
+
 def decode_step(params: Dict[str, Any], state: DecodeState,
                 batch: Dict[str, Array], cfg: ArchConfig,
                 pol: Optional[ExecutionPolicy] = None
                 ) -> Tuple[Array, DecodeState]:
     """One new token for every sequence. batch: {"tokens": (B,1)} or
-    {"frames": (B,1,D)}.  Returns (logits, new state)."""
+    {"frames": (B,1,D)}.  Returns (logits, new state).
+
+    The layer loop carries the stacked state (:func:`_scan_layers`):
+    layer l writes its new K/V column a row into the stacked cache, then
+    attends over its slice of it, and overwrites its slice of the
+    recurrent state (Mamba-2's or hymba's, rwkv6's).  Every other column
+    and layer is left as it was."""
     pol = pol or cfg.exec_policy
     if cfg.input_kind == "tokens":
         x = L.embedding_lookup(batch["tokens"], params["embed"])
@@ -448,7 +550,7 @@ def decode_step(params: Dict[str, Any], state: DecodeState,
     per_row = jnp.ndim(pos) == 1            # serving slots: own pos per row
     paged = getattr(state, "block_tables", None) is not None
     if state.cache_k is not None:
-        cache_len = state.cache_k.shape[2]
+        cache_len = state.cache_k.shape[kv_pos_axis(state)]
         if paged:   # pool (L,N,page,...): logical capacity is the table's
             cache_len = state.block_tables.shape[1] * cache_len
         if cfg.sliding_window and cache_len <= cfg.sliding_window:
@@ -459,76 +561,42 @@ def decode_step(params: Dict[str, Any], state: DecodeState,
     else:
         windows = jnp.asarray(layer_windows(cfg, 4096))
 
-    qc = _cache_quant(cfg)
-
-    def body(x, xs):
+    def body(x, bp, win, layer, st):
+        h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
         if cfg.family == "ssm":
-            if qc:
-                bp, xp, cp, wkv_q, wkv_s = xs
-                # dequant -> exact f32 recurrence step -> requant: the
-                # O(1) state round-trips through int8 once per token
-                wkv = dequantize_blocked(wkv_q, wkv_s)
-            else:
-                bp, xp, cp, wkv = xs
-            h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
+            # an int8 wkv state round-trips through the exact f32
+            # recurrence step once per token (_read_layer/_write_layer)
             tm_out, (xp2, wkv2) = S.rwkv6_timemix(
-                h, S.Rwkv6Params(**bp["tm"]), cfg, pol, (xp, wkv))
+                h, S.Rwkv6Params(**bp["tm"]), cfg, pol,
+                (_read_layer(st, "x_prev", layer),
+                 _read_layer(st, "wkv", layer)))
             x = x + tm_out
             h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
             cm_out, cp2 = S.rwkv6_channelmix(
-                h, S.Rwkv6ChannelParams(**bp["cm"]), cfg, pol, cp)
-            if qc:
-                wkv2, wkv2_s = quantize_blocked(wkv2)
-                return x + cm_out, (xp2, cp2, wkv2, wkv2_s)
-            return x + cm_out, (xp2, cp2, wkv2)
-
-        bp, ck, cv = xs[0], xs[1], xs[2]
-        if qc:
-            sk_, sv_, win = xs[3], xs[4], xs[5]
-            extra = xs[6:]
-        else:
-            win = xs[3]
-            extra = xs[4:]
-        h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
+                h, S.Rwkv6ChannelParams(**bp["cm"]), cfg, pol,
+                _read_layer(st, "cm_prev", layer))
+            return x + cm_out, _write_layer(st, layer, x_prev=xp2,
+                                            cm_prev=cp2, wkv=wkv2)
         positions = (pos[:, None].astype(jnp.int32) if per_row
                      else jnp.full((1,), pos, jnp.int32))
         q, k, v = A.qkv(h, _attn_params(bp, cfg), cfg, pol, positions)
+        scales = {n: st[n] for n in ("scale_k", "scale_v") if n in st}
         if paged:
-            if qc:
-                ctx, ck2, cv2, sk2, sv2 = A.paged_decode_attention(
-                    q, k, v, ck, cv, state.block_tables, pos, cfg, pol,
-                    win, scale_k=sk_, scale_v=sv_)
-                new_caches = (ck2, cv2, sk2, sv2)
-            else:
-                ctx, ck2, cv2 = A.paged_decode_attention(
-                    q, k, v, ck, cv, state.block_tables, pos, cfg, pol,
-                    win)
-                new_caches = (ck2, cv2)
-        elif qc:
-            ctx, ck2, cv2, sk2, sv2 = A.decode_attention(
-                q, k, v, ck, cv, pos, cfg, pol, win,
-                scale_k=sk_, scale_v=sv_)
-            new_caches = (ck2, cv2, sk2, sv2)
+            ctx, *caches = A.paged_decode_attention(
+                q, k, v, st["cache_k"], st["cache_v"], state.block_tables,
+                pos, cfg, pol, win, layer=layer, **scales)
         else:
-            ctx, ck2, cv2 = A.decode_attention(q, k, v, ck, cv, pos, cfg,
-                                               pol, win)
-            new_caches = (ck2, cv2)
+            ctx, *caches = A.decode_attention(
+                q, k, v, st["cache_k"], st["cache_v"], pos, cfg, pol, win,
+                layer=layer, **scales)
+        st = {**st, **dict(zip(_KV_FIELDS, caches))}
         attn_out = L.dense(ctx.reshape(b, 1, -1), bp["attn"]["wo"], pol)
-        new_extra = ()
         if cfg.family == "hybrid":
-            if qc:
-                tail, hq_, hs_ = extra
-                hprev = dequantize_blocked(hq_, hs_)
-            else:
-                tail, hprev = extra
-            ssm_out, (tail2, h2) = _ssm_branch(h, bp, cfg, pol,
-                                               (tail, hprev))
+            ssm_out, (tail2, h2) = _ssm_branch(
+                h, bp, cfg, pol, (_read_layer(st, "conv_tail", layer),
+                                  _read_layer(st, "ssm_h", layer)))
             x = _fuse(x, attn_out, ssm_out, bp, cfg)
-            if qc:
-                h2, h2_s = quantize_blocked(h2)
-                new_extra = (tail2, h2, h2_s)
-            else:
-                new_extra = (tail2, h2)
+            st = _write_layer(st, layer, conv_tail=tail2, ssm_h=h2)
         else:
             x = x + attn_out
         h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -543,49 +611,10 @@ def decode_step(params: Dict[str, Any], state: DecodeState,
         else:
             x = x + L.swiglu(h, bp["ffn"]["w_gate"], bp["ffn"]["w_up"],
                              bp["ffn"]["w_down"], pol, cfg.activation)
-        return x, new_caches + new_extra
+        return x, st
 
-    if cfg.family == "ssm":
-        if qc:
-            x, (xp, cp, wkv, wkv_s) = jax.lax.scan(
-                body, x, (params["blocks"], state.x_prev, state.cm_prev,
-                          state.wkv, state.wkv_scale))
-            new_state = state._replace(x_prev=xp, cm_prev=cp, wkv=wkv,
-                                       wkv_scale=wkv_s, pos=pos + 1)
-        else:
-            x, (xp, cp, wkv) = jax.lax.scan(
-                body, x, (params["blocks"], state.x_prev, state.cm_prev,
-                          state.wkv))
-            new_state = state._replace(x_prev=xp, cm_prev=cp, wkv=wkv,
-                                       pos=pos + 1)
-    elif cfg.family == "hybrid":
-        if qc:
-            x, (ck, cv, sk, sv, tail, hh, hs) = jax.lax.scan(
-                body, x, (params["blocks"], state.cache_k, state.cache_v,
-                          state.scale_k, state.scale_v, windows,
-                          state.conv_tail, state.ssm_h, state.ssm_scale))
-            new_state = state._replace(cache_k=ck, cache_v=cv, scale_k=sk,
-                                       scale_v=sv, conv_tail=tail, ssm_h=hh,
-                                       ssm_scale=hs, pos=pos + 1)
-        else:
-            x, (ck, cv, tail, hh) = jax.lax.scan(
-                body, x, (params["blocks"], state.cache_k, state.cache_v,
-                          windows, state.conv_tail, state.ssm_h))
-            new_state = state._replace(cache_k=ck, cache_v=cv,
-                                       conv_tail=tail, ssm_h=hh, pos=pos + 1)
-    else:
-        if qc:
-            x, (ck, cv, sk, sv) = jax.lax.scan(
-                body, x, (params["blocks"], state.cache_k, state.cache_v,
-                          state.scale_k, state.scale_v, windows))
-            new_state = state._replace(cache_k=ck, cache_v=cv, scale_k=sk,
-                                       scale_v=sv, pos=pos + 1)
-        else:
-            x, (ck, cv) = jax.lax.scan(
-                body, x, (params["blocks"], state.cache_k, state.cache_v,
-                          windows))
-            new_state = state._replace(cache_k=ck, cache_v=cv, pos=pos + 1)
-
+    x, new_state = _scan_layers(body, x, params, windows, state, paged)
+    new_state = new_state._replace(pos=pos + 1)
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = _lm_head(x, params, pol)
     if cfg.n_codebooks:
@@ -673,11 +702,13 @@ def prefill(params, batch, cfg: ArchConfig,
     qc = _cache_quant(cfg)
 
     def pad_seq(t):
-        # zero-pad along the sequence axis up to the slot cache length
-        tgt = state.cache_k.shape[2]
-        if t.shape[2] != tgt:
-            t = jnp.pad(t, ((0, 0), (0, 0), (0, tgt - t.shape[2]))
-                        + ((0, 0),) * (t.ndim - 3))
+        # (L,B,S,Hkv,...) layer outputs -> the head-major cache layout,
+        # zero-padded along the sequence axis to the slot cache length
+        t = jnp.swapaxes(t, 2, 3)
+        tgt = state.cache_k.shape[3]
+        if t.shape[3] != tgt:
+            t = jnp.pad(t, ((0, 0),) * 3 + ((0, tgt - t.shape[3]),)
+                        + ((0, 0),) * (t.ndim - 4))
         return t
 
     def pad_cache(t):
@@ -689,8 +720,7 @@ def prefill(params, batch, cfg: ArchConfig,
         # through the legacy fixed-scale path.
         if state.cache_k.dtype == jnp.int8 and t.dtype != jnp.int8:
             t = A.quantize_kv(t)
-        return constrain(pad_seq(t),
-                         ("layers", "batch", "seq", "kv_heads", None))
+        return constrain(pad_seq(t), _CACHE_AXES)
 
     pos = (jnp.int32(s) if lengths is None else lengths.astype(jnp.int32))
     if cfg.family == "ssm":
@@ -787,15 +817,14 @@ def slot_update(state: DecodeState, sub: DecodeState, slots: Array
             src = jnp.broadcast_to(src.astype(tgt.dtype), slots.shape)
             out[name] = tgt.at[slots].set(src, mode="drop")
             continue
-        if name in ("cache_k", "cache_v", "scale_k", "scale_v") \
-                and src.shape[2] != tgt.shape[2]:
-            grow = tgt.shape[2] - src.shape[2]
+        if name in _KV_FIELDS and src.shape[3] != tgt.shape[3]:
+            grow = tgt.shape[3] - src.shape[3]
             if grow < 0:
                 raise ValueError(
-                    f"prefill cache ({src.shape[2]}) exceeds slot cache "
-                    f"({tgt.shape[2]}); raise the engine's max_seq")
-            src = jnp.pad(src, [(0, 0), (0, 0), (0, grow)]
-                          + [(0, 0)] * (src.ndim - 3))
+                    f"prefill cache ({src.shape[3]}) exceeds slot cache "
+                    f"({tgt.shape[3]}); raise the engine's max_seq")
+            src = jnp.pad(src, [(0, 0)] * 3 + [(0, grow)]
+                          + [(0, 0)] * (src.ndim - 4))
         out[name] = tgt.at[:, slots].set(src.astype(tgt.dtype), mode="drop")
     return DecodeState(**out)
 
@@ -836,9 +865,6 @@ def slot_extract(state: DecodeState, slots: Array) -> DecodeState:
 # by the age mask until the real token at that position overwrites it).
 REC_FIELDS = ("x_prev", "cm_prev", "wkv", "conv_tail", "ssm_h")
 
-# quantized-cache mode: the rec fields that live as int8 and the scale
-# field each one re-derives at spec_commit time
-_SCALE_FOR = {"wkv": "wkv_scale", "ssm_h": "ssm_scale"}
 
 # ring-cache verify: rec_stack keys carrying the raw evicted K/V columns
 # (L, B, K, ...) that spec_commit restores for rejected candidates, and
@@ -894,7 +920,7 @@ def verify_step(params: Dict[str, Any], state: DecodeState,
     paged = getattr(state, "block_tables", None) is not None
     ring = False
     if state.cache_k is not None:
-        cache_len = state.cache_k.shape[2]
+        cache_len = state.cache_k.shape[kv_pos_axis(state)]
         if paged:   # pool (L,N,page,...): logical capacity is the table's
             cache_len = state.block_tables.shape[1] * cache_len
         if cfg.sliding_window and cache_len <= cfg.sliding_window:
@@ -1132,26 +1158,17 @@ def spec_commit(state: DecodeState, rec_stack: Dict[str, Array],
     rec_stack = {k: v for k, v in rec_stack.items() if k not in ring_cols}
     out: Dict[str, Any] = {"pos": state.pos + advance.astype(state.pos.dtype)}
     for name, ev in ring_cols.items():            # ev (L, B, K, ...)
-        cache = getattr(state, _RING_FIELD[name])
+        cache = getattr(state, _RING_FIELD[name])    # (L,B,Hkv,S,...)
         nb, kq = ev.shape[1], ev.shape[2]
-        s_max = cache.shape[2]
         offs = jnp.arange(kq, dtype=jnp.int32)
-        if jnp.ndim(advance) == 0:
-            slots = jnp.mod(state.pos.astype(jnp.int32) + offs, s_max)
-            rej = offs >= advance                              # (K,)
-            cur = cache[:, :, slots]                           # (L,B,K,...)
-            sel = rej.reshape((1, 1, kq) + (1,) * (ev.ndim - 3))
-            out[_RING_FIELD[name]] = cache.at[:, :, slots].set(
-                jnp.where(sel, ev, cur))
-        else:
-            posv = jnp.broadcast_to(state.pos, (nb,)).astype(jnp.int32)
-            slots = jnp.mod(posv[:, None] + offs[None, :], s_max)  # (B,K)
-            rej = offs[None, :] >= advance[:, None]                # (B,K)
-            rows = jnp.arange(nb)[:, None]
-            cur = cache[:, rows, slots]                        # (L,B,K,...)
-            sel = rej.reshape((1, nb, kq) + (1,) * (ev.ndim - 3))
-            out[_RING_FIELD[name]] = cache.at[:, rows, slots].set(
-                jnp.where(sel, ev, cur))
+        posv = jnp.broadcast_to(state.pos, (nb,)).astype(jnp.int32)
+        slots = jnp.mod(posv[:, None] + offs[None, :], cache.shape[3])
+        rej = offs[None, :] >= jnp.broadcast_to(advance, (nb,))[:, None]
+        idx = (slice(None),) + A.kv_index(jnp.arange(nb)[:, None], slots,
+                                           cache.shape[2])
+        sel = rej.reshape((1, nb, kq) + (1,) * (ev.ndim - 3))
+        out[_RING_FIELD[name]] = cache.at[idx].set(
+            jnp.where(sel, ev, cache[idx]))
     for name, stack in rec_stack.items():         # stack (K+1, L, B, ...)
         if jnp.ndim(advance) == 0:
             picked = stack[advance]

@@ -245,8 +245,10 @@ class DraftModelDrafter(Drafter):
                     f"draft model family {cfg.family!r} keeps recurrent "
                     f"state ({name}); the drafter's position-reset "
                     f"rollback needs a pure-KV-cache (attention) family")
+        from repro.models.transformer import kv_pos_axis
         if (abs_state.cache_k is not None
-                and abs_state.cache_k.shape[2] < self._alloc):
+                and abs_state.cache_k.shape[kv_pos_axis(abs_state)]
+                < self._alloc):
             raise ValueError("draft model allocates a ring cache; the "
                              "drafter needs a linear cache for rollback")
         self._bucket_cap = 1 << (self._alloc.bit_length() - 1)

@@ -69,6 +69,8 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import CacheSpec
 from repro.kernels import common as kernel_common
 from repro.models.model_zoo import Model
+from repro.models.transformer import (kv_pos_axis, slot_kv_history,
+                                      slot_kv_leaf)
 from repro.parallel.fault_tolerance import WorkerKilled
 from repro.runtime.block_pool import BlockAllocator, RadixCache
 from repro.runtime.drafter import (Drafter, DraftSession, NGramDrafter,
@@ -592,13 +594,13 @@ class ServeEngine:
             # construction (their init refuses ring configs).
             abs_state = self.ops.init_slot_state(max_batch, max_seq,
                                                  abstract=True)
-            if (abs_state.cache_k is not None
-                    and abs_state.cache_k.shape[2] < max_seq
-                    and k_max + 1 > abs_state.cache_k.shape[2]):
+            ring_len = (abs_state.cache_k.shape[kv_pos_axis(abs_state)]
+                        if abs_state.cache_k is not None else max_seq)
+            if ring_len < max_seq and k_max + 1 > ring_len:
                 raise ValueError(
                     f"speculative verify window k+1={k_max + 1} exceeds "
                     f"the sliding-window ring cache "
-                    f"({abs_state.cache_k.shape[2]} slots); lower "
+                    f"({ring_len} slots); lower "
                     f"spec_k/spec_k_max below the window")
         self.spec_k = int(spec_k)
         self.spec_k_max = k_max
@@ -1423,7 +1425,7 @@ class ServeEngine:
         state = self._state
         if (not self.paged and state is not None
                 and state.cache_k is not None
-                and state.cache_k.shape[2] < self.max_seq):
+                and state.cache_k.shape[kv_pos_axis(state)] < self.max_seq):
             raise ValueError(
                 "cannot snapshot a ring-cache engine (slot cache shorter "
                 "than max_seq): ring positions alias, so a linear per-slot "
@@ -1450,7 +1452,8 @@ class ServeEngine:
             arrays[f"slot{j}.tokens"] = np.asarray(slot.tokens, np.int32)
             for name, arr in host.items():
                 if name in self._KV_LEAVES and not self.paged:
-                    arrays[f"slot{j}.{name}"] = arr[:, j, :pos].copy()
+                    arrays[f"slot{j}.{name}"] = slot_kv_history(
+                        arr, j, pos).copy()
                 else:
                     arrays[f"slot{j}.{name}"] = arr[:, j].copy()
             if self.paged and self.allocator is not None:
@@ -1705,7 +1708,7 @@ class ServeEngine:
         the bucket of its position, the shape a one-row prefill of that
         bucket inserts, so a warm engine restores without a new trace."""
         state = self._state
-        cache_len = (state.cache_k.shape[2]
+        cache_len = (state.cache_k.shape[kv_pos_axis(state)]
                      if state.cache_k is not None else None)
         bk = self._bucket(e.pos)
         if cache_len is not None and bk < e.pos:
@@ -1718,11 +1721,7 @@ class ServeEngine:
             elif name == "pos":
                 fields[name] = np.array([e.pos], np.int32)
             elif name in self._KV_LEAVES:
-                fields[name] = np.zeros(
-                    (leaf.shape[0], 1, bk) + tuple(leaf.shape[3:]),
-                    leaf.dtype)
-                if name in e.leaves:
-                    fields[name][:, 0, :e.pos] = e.leaves[name]
+                fields[name] = slot_kv_leaf(leaf, bk, e.leaves.get(name))
             else:
                 fields[name] = np.zeros(
                     (leaf.shape[0], 1) + tuple(leaf.shape[2:]), leaf.dtype)

@@ -172,7 +172,7 @@ def test_hymba_long_mode_ring_cache():
     cfg = get_arch("hymba-1.5b")
     model = build_model(cfg)
     st = model.init_decode_state(1, 524288, abstract=True)
-    assert st.cache_k.shape[2] == cfg.sliding_window
+    assert st.cache_k.shape[3] == cfg.sliding_window     # (L,B,Hkv,S,dh)
 
 
 def test_int8_kv_cache_decode_fidelity():
@@ -332,3 +332,139 @@ def test_mamba2_step_checkpoints_are_one_loop_not_unrolled():
     _, (tail_6, _) = S.mamba2_mix(x[:, :6], p, cfg, cfg.exec_policy, st)
     np.testing.assert_array_equal(np.asarray(tails[:, 5]),
                                   np.asarray(tail_6))
+
+
+# -- the decode layer loop writes each layer's slice in place ----------------
+
+# (arch, cache format) of each case: dense GQA, Falcon-H1's hybrid
+# (attention beside Mamba-2), rwkv6, the per-block int8 cache, the paged pool
+_IN_PLACE = {"dense": ("glm4-9b", {}),
+             "hybrid": ("falcon-h1-34b", {}),
+             "rwkv6": ("rwkv6-3b", {}),
+             "int8": ("glm4-9b", {"dtype": "int8"}),
+             "paged": ("glm4-9b", {"paged": True, "page_size": 8})}
+_KV = ("cache_k", "cache_v", "scale_k", "scale_v")
+
+
+def _python_scan(f, init, xs=None, length=None, reverse=False, unroll=1):
+    """``jax.lax.scan`` as a plain Python loop over the leading axis."""
+    assert not reverse
+    n = length if xs is None else jax.tree.leaves(xs)[0].shape[0]
+    carry, ys = init, []
+    for i in range(n):
+        carry, y = f(carry, None if xs is None
+                     else jax.tree.map(lambda a: a[i], xs))
+        ys.append(y)
+    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+
+def _random_slot_state(model, cfg, b, s, page, rng):
+    """A slot state with every leaf drawn at random, so an unwritten
+    entry that changed shows."""
+    if model.cfg.cache_spec().paged:
+        n = b * s // page + 2
+        st = model.cache_ops(num_blocks=n).init_slot_state(b, s)
+    else:
+        st = model.init_slot_state(b, s)
+    out = {}
+    for name in st._fields:
+        v = getattr(st, name)
+        if v is None or name == "pos":
+            continue
+        if name == "block_tables":
+            t = rng.permutation(n)[:v.size].reshape(v.shape)
+            t[0, -1] = n                    # one unallocated page
+            out[name] = jnp.asarray(t, jnp.int32)
+        elif v.dtype == jnp.int8:
+            out[name] = jnp.asarray(rng.integers(-100, 100, v.shape), v.dtype)
+        elif name.startswith("scale") or name.endswith("_scale"):
+            out[name] = jnp.asarray(rng.uniform(1e-3, 2e-2, v.shape), v.dtype)
+        else:
+            out[name] = jnp.asarray(rng.normal(0, 0.5, v.shape), v.dtype)
+    # per-row positions: mid-cache, the last column, and one past the
+    # end (a dense cache ring-writes it at column 8, a pool drops it)
+    return st._replace(pos=jnp.asarray([5, s - 1, s + 8], jnp.int32), **out)
+
+
+@pytest.mark.parametrize("case", sorted(_IN_PLACE))
+def test_decode_step_writes_only_each_layers_slice(case):
+    """One ``decode_step`` over per-row positions changes each layer's K/V
+    only at each row's column ``pos % S`` (a pool: at its page slot, none
+    past the table), that column holds the layer's new K/V, and the
+    logits and state are bit for bit those of the same per-layer body
+    run as a plain Python loop, with nothing carried through a scan."""
+    from unittest import mock
+
+    from repro.configs.base import CacheSpec
+    from repro.core.quant_cache import quantize_blocked
+    from repro.models import attention as A
+
+    arch, spec = _IN_PLACE[case]
+    # float32: in bf16, XLA fuses the unrolled loop's elementwise chains
+    # across layers and may keep a value unrounded that the scanned body
+    # rounds, so only float32 compares the loop's structure bit for bit
+    cfg = get_arch(arch).reduced().scaled(dtype="float32")
+    model = build_model(cfg).with_cache_spec(CacheSpec(**spec))
+    params = model.init(jax.random.PRNGKey(0))
+    b, s, page = 3, 32, 8
+    rng = np.random.default_rng(11)
+    st0 = _random_slot_state(model, cfg, b, s, page, rng)
+    toks = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (b, 1)),
+                                  jnp.int32)}
+    logits, st1 = jax.jit(model.decode_step)(params, st0, toks)
+
+    # the reference: the layers (and every inner scan) as Python loops in
+    # one program, returning each layer's new K/V as the body computes it
+    qkv = A.qkv
+
+    def unscanned(p, st, t):
+        new_kv = []
+
+        def recorded(*a, **kw):
+            out = qkv(*a, **kw)
+            new_kv.append(out[1:])
+            return out
+
+        with mock.patch.object(jax.lax, "scan", _python_scan), \
+                mock.patch.object(A, "qkv", recorded):
+            return model.decode_step(p, st, t) + (new_kv,)
+
+    ref_logits, ref, new_kv = jax.jit(unscanned)(params, st0, toks)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    for name in st1._fields:
+        if getattr(st1, name) is not None:
+            np.testing.assert_array_equal(np.asarray(getattr(st1, name)),
+                                          np.asarray(getattr(ref, name)),
+                                          err_msg=name)
+    if cfg.family == "ssm":
+        assert st0.cache_k is None
+        return
+
+    pos = np.asarray(st0.pos)
+    assert len(new_kv) == cfg.n_layers
+    for name in _KV:
+        old, new = getattr(st0, name), getattr(st1, name)
+        if old is None:
+            continue
+        old, new = np.asarray(old), np.asarray(new)
+        written = np.zeros(old.shape, bool)
+        for layer, (k, v) in enumerate(new_kv):
+            col = (k if name.endswith("_k") else v)[:, 0]   # (B, Hkv, dh)
+            if spec.get("dtype") == "int8":
+                q_, sc = quantize_blocked(col)
+                col = sc if name.startswith("scale") else q_
+            col = np.asarray(col.astype(old.dtype))
+            for row in range(b):
+                if spec.get("paged"):               # (L, N, page, Hkv, dh)
+                    blk = int(st0.block_tables[row, pos[row] // page]) \
+                        if pos[row] < s else None
+                    if blk is None or blk >= old.shape[1]:
+                        continue                    # the write drops
+                    at = (layer, blk, pos[row] % page)
+                else:                               # (L, B, Hkv, S, dh)
+                    at = (layer, row, slice(None), pos[row] % s)
+                written[at] = True
+                np.testing.assert_array_equal(new[at], col[row])
+        np.testing.assert_array_equal(new[~written], old[~written],
+                                      err_msg=name)
+        assert written.any()

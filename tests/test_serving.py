@@ -220,10 +220,10 @@ def test_slot_update_scatter_and_sentinel(served):
                            headroom=0, lengths=lengths)
     # rows 0,1 go to slots 2,0; rows 2,3 carry the drop sentinel (=4)
     state2 = model.slot_update(state, sub, jnp.asarray([2, 0, 4, 4]))
-    assert state2.cache_k.shape[2] == MAX_SEQ   # bucket padded up
+    assert state2.cache_k.shape[3] == MAX_SEQ   # bucket padded up
     np.testing.assert_array_equal(np.asarray(state2.pos), [5, 0, 5, 0])
     np.testing.assert_array_equal(
-        np.asarray(state2.cache_k[:, 2, :16].astype(jnp.float32)),
+        np.asarray(state2.cache_k[:, 2, :, :16].astype(jnp.float32)),
         np.asarray(sub.cache_k[:, 0].astype(jnp.float32)))
     # untouched slots keep their (zero) state
     assert float(jnp.abs(state2.cache_k[:, 1].astype(jnp.float32)).sum()) == 0

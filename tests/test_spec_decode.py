@@ -346,7 +346,7 @@ def test_ring_cache_spec_greedy_bitexact(served):
                                                   max_seq=RING_SEQ,
                                                   spec_k=4))
     st = spec._init_state()
-    assert st.cache_k.shape[2] == window     # really a ring allocation
+    assert st.cache_k.shape[3] == window     # really a ring allocation
     done = spec.serve(reqs())
     for r in done:
         assert list(r.output) == ref[r.rid], r.rid

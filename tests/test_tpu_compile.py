@@ -165,9 +165,9 @@ _BOOKKEEPING = {"parameter", "get-tuple-element", "tuple", "bitcast",
                 "constant"}
 
 
-def _loop_body_sizes(hlo: str):
-    """Device ops (bookkeeping left out) in each while loop's body of a
-    compiled module's text."""
+def _module_ops(hlo: str):
+    """Device ops (bookkeeping left out) of each computation of a compiled
+    module's text: {computation: [(op, [output shapes])]}."""
     ops, name = {}, None
     for line in hlo.splitlines():
         head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
@@ -175,12 +175,34 @@ def _loop_body_sizes(hlo: str):
             name = head.group(1)
             ops[name] = []
         elif name and line.startswith("  ") and " = " in line:
-            op = re.search(r"\s([a-z][a-z0-9\-]*)\((?:%|\))",
-                           line.split(" = ", 1)[1])
+            rhs = line.split(" = ", 1)[1]
+            op = re.search(r"\s([a-z][a-z0-9\-]*)\((?:%|\))", rhs)
             if op and op.group(1) not in _BOOKKEEPING:
-                ops[name].append(op.group(1))
-    bodies = re.findall(r" while\(.*?body=%([\w.\-]+)", hlo)
-    return [len(ops[b]) for b in bodies]
+                shapes = [tuple(int(d) for d in dims.split(",") if d)
+                          for dims in re.findall(r"[a-z0-9]+\[([\d,]*)\]",
+                                                 rhs[:op.start()])]
+                ops[name].append((op.group(1), shapes))
+    return ops
+
+
+def _loop_bodies(hlo: str):
+    """The device ops of each while loop's body, as :func:`_module_ops`."""
+    ops = _module_ops(hlo)
+    return [ops[b] for b in re.findall(r" while\(.*?body=%([\w.\-]+)", hlo)]
+
+
+def _loop_body_sizes(hlo: str):
+    """Device ops (bookkeeping left out) in each while loop's body of a
+    compiled module's text."""
+    return [len(body) for body in _loop_bodies(hlo)]
+
+
+def _stacked_state_copies(hlo: str, state):
+    """Copies the module makes of a whole stacked state leaf."""
+    stacked = {leaf.shape for leaf in jax.tree.leaves(state)
+               if leaf.ndim > 2}
+    return [(op, shapes) for ops in _module_ops(hlo).values()
+            for op, shapes in ops if op == "copy" and set(shapes) & stacked]
 
 
 def test_rwkv6_prefill_runs_no_loop_a_token(one_chip):
@@ -202,6 +224,30 @@ def test_rwkv6_prefill_runs_no_loop_a_token(one_chip):
     ).lower(params, tokens, lengths).compile().as_text()
     sizes = _loop_body_sizes(hlo)
     assert sizes and min(sizes) >= 64, sizes
+
+
+def test_rwkv6_decode_step_updates_state_in_place(one_chip):
+    """rwkv6-3b's decode step (published widths, 2 of its 32 layers, 16
+    rows) carries its stacked token-shift boundaries and wkv state
+    through the layer loop: no second stacked copy of the state is made
+    (temps under one wkv stack, 21 MB) and none is copied back after the
+    loop."""
+    cfg = get_arch("rwkv6-3b").scaled(n_layers=2)
+    model = build_model(cfg)
+
+    def place(t):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), t)
+
+    params = place(model.abstract_params())
+    state = place(model.init_slot_state(16, 2048, abstract=True))
+    tokens = _sds((16, 1), jnp.int32, one_chip)
+    compiled = jax.jit(
+        lambda p, s, t: model.decode_step(p, s, {"tokens": t}),
+        donate_argnums=(1,)).lower(params, state, tokens).compile()
+    wkv_bytes = state.wkv.size * state.wkv.dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < wkv_bytes, (temp, wkv_bytes)
+    assert not _stacked_state_copies(compiled.as_text(), state)
 
 
 def test_falcon_h1_serves_at_published_widths_on_one_chip(one_chip):
@@ -228,6 +274,19 @@ def test_falcon_h1_serves_at_published_widths_on_one_chip(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 12e9    # weights, cache and state
     assert total < V5E_HBM_BYTES, total
+    # the layer loop carries the stacked cache and state and writes each
+    # layer's slice in place: no second stacked copy (2.1 GB) is made
+    # inside or after the loop, and the loop reads a layer's K/V in place
+    # (a whole layer's cache, 143 MB, in any layout, is never an output)
+    assert mem.temp_size_in_bytes < 1e9, mem.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert not _stacked_state_copies(hlo, state)
+    layer_cache = sorted(state.cache_k.shape[1:])
+    (body,) = _loop_bodies(hlo)
+    whole = [(op, shapes) for op, shapes in body
+             if any(sorted(d for d in sh if d > 1) == layer_cache
+                    for sh in shapes)]
+    assert not whole, whole
 
     tokens = _sds((1, 512), jnp.int32, one_chip)
     lengths = _sds((1,), jnp.int32, one_chip)
